@@ -72,6 +72,7 @@ from repro.core.transport import (
     SleepWork,
     WorkerServer,
 )
+from repro.launch.compile_cache import configure_compile_cache
 
 BENCH_SCHEMA = "bench_dispatch/v2"
 
@@ -359,6 +360,7 @@ def main() -> None:
     ap.add_argument("--json", metavar="PATH",
                     help="write the bench_dispatch/v2 artifact here")
     args = ap.parse_args()
+    configure_compile_cache()
     result = run(quick=args.quick, seed=args.seed,
                  batch_frames=args.batch_frames)
     if args.json:

@@ -44,6 +44,7 @@ from repro.checkpoint import (
 )
 from repro.core import ElasticSchedule, HeteroRuntime, SimulatedClock
 from repro.core.scheduler import WorkerKind
+from repro.launch.compile_cache import configure_compile_cache
 
 BENCH_SCHEMA = "bench_fleet/v1"
 
@@ -168,6 +169,7 @@ def main() -> None:
     ap.add_argument("--json", metavar="PATH",
                     help="write the artifact to PATH")
     args = ap.parse_args()
+    configure_compile_cache()
     doc = run_bench(quick=args.quick)
     rec, ch = doc["recovery"], doc["churn"]
     print(f"recovery: full {rec['full_recompute_s']:.1f}s vs resume "

@@ -15,6 +15,7 @@ import numpy as np
 
 from repro.configs import get_config
 from repro.models import make_model
+from repro.launch.compile_cache import configure_compile_cache
 from repro.serving import LoadgenScenario, Request, ServingEngine
 from repro.serving.loadgen import make_trace, run_trace
 
@@ -230,6 +231,7 @@ def main() -> None:
                     help="runs per --loadgen config, median reported "
                          "(default: 1 with --quick, 3 otherwise)")
     args = ap.parse_args()
+    configure_compile_cache()
     if args.loadgen:
         result = loadgen_sweep(
             quick=args.quick,

@@ -18,6 +18,8 @@ from __future__ import annotations
 import argparse
 import sys
 
+from repro.launch.compile_cache import configure_compile_cache
+
 
 def main() -> None:
     ap = argparse.ArgumentParser()
@@ -25,6 +27,7 @@ def main() -> None:
                     help="reduced sizes (CI-scale)")
     ap.add_argument("--skip-table1", action="store_true")
     args = ap.parse_args()
+    configure_compile_cache()
     quick = args.quick
 
     rows = []

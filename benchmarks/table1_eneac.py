@@ -31,6 +31,7 @@ from repro.core.interrupts import RunReport
 from repro.kernels.hotspot.ref import hotspot_step_ref
 from repro.kernels.spmm.ref import make_problem, spmm_ell_ref, to_block_ell
 from repro.kernels.spmm.ops import pad_rhs
+from repro.launch.compile_cache import configure_compile_cache
 
 N_CC = 4
 N_ACC = 4
@@ -404,6 +405,7 @@ def main() -> None:
                     help="with --costmodel: write the artifact here "
                          "(default: stdout)")
     args = ap.parse_args()
+    configure_compile_cache()
     if args.costmodel:
         doc = costmodel_bench(seeds=args.seeds,
                               n_items=2048 if args.quick else 4096)

@@ -19,10 +19,10 @@ that boundary:
   CPU core (the paper's CC).  The default wall-clock backend.
 * :class:`ProcessPoolUnit` — a single-worker process pool, modelling a
   separate CPU (no GIL sharing).  Work functions must be picklable.
-* :class:`JaxDeviceUnit` — dispatches the work function onto a jax
-  device's stream: jitted calls return immediately (XLA async dispatch)
-  and a waiter thread turns ``block_until_ready`` into the completion
-  signal.  Degrades to :class:`ThreadUnit` semantics when jax is absent.
+* :class:`JaxDeviceUnit` — dispatches the work function onto one jax
+  device's stream (``"jax:<i>"`` binds ``jax.devices()[i]``): jitted
+  calls return immediately (XLA async dispatch) and a waiter thread turns
+  ``block_until_ready`` into the completion signal.
 * :class:`~repro.core.transport.RemoteUnit` (in :mod:`repro.core.transport`)
   — the same protocol stretched across a process/host boundary: submits
   become frames on a :class:`~repro.core.transport.Transport`, and a
@@ -83,7 +83,8 @@ BACKENDS = ("inline", "thread", "process", "jax", "remote")
 # The full spec grammar, quoted once so every "unknown backend" error can
 # list it (tests pin this — an unknown spec must teach the valid ones).
 VALID_BACKEND_SPECS = (
-    "'inline'", "'thread'/'threads'", "'process'/'processes'", "'jax'",
+    "'inline'", "'thread'/'threads'", "'process'/'processes'",
+    "'jax' (device 0) or 'jax:<i>' (device i)",
     "'remote:<host:port>' (optional '?batch_frames=N&fn_cache=0|1"
     "&heartbeat=SECS&patience=N' suffix)",
 )
@@ -236,6 +237,9 @@ class BackendUnit:
     kind_name = "backend"
     #: max chunks the engine may keep in flight on this unit at once
     capacity = 1
+    #: where the unit computes, as ``jax.Device.platform`` names it; every
+    #: unit but a :class:`JaxDeviceUnit` on an accelerator runs on the host
+    platform = "cpu"
 
     def __init__(self, name: str) -> None:
         self.name = name
@@ -340,6 +344,20 @@ class ThreadUnit(BackendUnit):
         super().close()
 
 
+def _cpu_only_child() -> None:
+    """Pool initializer: the child runs JAX on the CPU, never the chip.
+
+    An accelerator belongs to one process at a time and the parent holds
+    it; a child that touched it would fail or hang.
+    """
+    import os
+    import sys
+
+    os.environ["JAX_PLATFORMS"] = "cpu"
+    if "jax" in sys.modules:  # imported with the parent's main module
+        sys.modules["jax"].config.update("jax_platforms", "cpu")
+
+
 def _process_entry(work_fn: WorkFn, chunk: Chunk, submitted: float):
     """Runs in the pool worker; perf_counter is CLOCK_MONOTONIC, which is
     system-wide on Linux, so the dispatch latency spans the process hop."""
@@ -354,7 +372,8 @@ class ProcessPoolUnit(BackendUnit):
 
     Work functions (and their closures) must be picklable, and side
     effects land in the *worker* process: callers get results back via
-    :attr:`CompletionRecord.result`, not shared memory.  If the host
+    :attr:`CompletionRecord.result`, not shared memory.  The worker runs
+    JAX on the CPU only (see :func:`_cpu_only_child`).  If the host
     cannot spawn processes (sandboxed CI), the unit degrades to in-thread
     execution and sets :attr:`degraded`.
     """
@@ -379,6 +398,7 @@ class ProcessPoolUnit(BackendUnit):
                 self._pool = ProcessPoolExecutor(
                     max_workers=1,
                     mp_context=multiprocessing.get_context("spawn"),
+                    initializer=_cpu_only_child,
                 )
                 # force worker spawn now so a broken sandbox fails fast
                 self._pool.submit(int, 0).result(timeout=60)
@@ -423,17 +443,8 @@ class ProcessPoolUnit(BackendUnit):
         super().close()
 
 
-def _jax_module():
-    """Import hook the tests monkeypatch to simulate a jax-less host."""
-    try:
-        import jax
-    except Exception:  # pragma: no cover - depends on environment
-        return None
-    return jax
-
-
 class JaxDeviceUnit(BackendUnit):
-    """Dispatch onto a jax device stream via non-blocking jit calls.
+    """Dispatch onto one jax device's stream via non-blocking jit calls.
 
     ``submit`` invokes the work function under ``jax.default_device``:
     jitted computations are *enqueued* on the device and return
@@ -444,39 +455,43 @@ class JaxDeviceUnit(BackendUnit):
     nothing to block on, so completion fires after dispatch), but then
     the elapsed time only covers the host-side call.
 
-    When jax is unavailable the unit degrades to a :class:`ThreadUnit`
-    (synchronous execution on a dedicated thread) and sets
-    :attr:`degraded` — callers keep working, just without device overlap.
+    ``device`` is a ``jax.Device`` or an index into ``jax.devices()``
+    (default 0).  An index that does not exist raises; it never wraps.
+    A work function whose arrays are committed to another device runs
+    there, so callers place each unit's operands on :attr:`device`.
     """
 
     kind_name = "jax"
 
-    def __init__(self, name: str, device=None) -> None:
+    def __init__(self, name: str, device=0) -> None:
         super().__init__(name)
-        self._requested_device = device
-        self._device = None
-        self.degraded = False
-        self._fallback: Optional[ThreadUnit] = None
+        import jax
+
+        if isinstance(device, int):
+            devices = jax.devices()
+            if not 0 <= device < len(devices):
+                raise ValueError(
+                    f"unit {name!r}: jax device index {device} does not "
+                    f"exist ({len(devices)} device(s): "
+                    f"{devices[0].platform} x{len(devices)})"
+                )
+            device = devices[device]
+        self._jax = jax
+        self._device = device
         self._waitq: Optional[queue.Queue] = None
         self._waiter: Optional[threading.Thread] = None
-        self._jax = None
+
+    @property
+    def device(self):
+        """The ``jax.Device`` this unit computes on."""
+        return self._device
+
+    @property
+    def platform(self) -> str:
+        return self._device.platform
 
     def start(self, bus: CompletionBus) -> None:
         super().start(bus)
-        self._jax = _jax_module()
-        if self._jax is None:
-            self.degraded = True
-            if self._fallback is None:
-                self._fallback = ThreadUnit(self.name)
-            self._fallback.start(bus)
-            self._fallback.dispatch_latencies = self.dispatch_latencies
-            return
-        if self._device is None:
-            self._device = (
-                self._requested_device
-                if self._requested_device is not None
-                else self._jax.devices()[0]
-            )
         if self._waiter is None or not self._waiter.is_alive():
             self._waitq = queue.Queue()
             self._waiter = threading.Thread(
@@ -504,10 +519,6 @@ class JaxDeviceUnit(BackendUnit):
             ))
 
     def submit(self, chunk: Chunk, work_fn: WorkFn) -> None:
-        if self.degraded:
-            assert self._fallback is not None
-            self._fallback.submit(chunk, work_fn)
-            return
         submitted = time.perf_counter()
         out, error = None, None
         try:
@@ -523,9 +534,6 @@ class JaxDeviceUnit(BackendUnit):
             self._waiter.join(timeout=10.0)
         self._waiter = None
         self._waitq = None
-        if self._fallback is not None:
-            self._fallback.close()
-            self._fallback = None
         super().close()
 
 
@@ -599,11 +607,18 @@ def make_backend(spec: Union[str, BackendUnit, None], name: str) -> BackendUnit:
             heartbeat=opts.get("heartbeat"),
             patience=int(opts.get("patience", 3)),
         )
+    if text == "jax" or text.startswith("jax:"):
+        index = text[len("jax:"):] if text != "jax" else "0"
+        if not index.isdigit():
+            raise ValueError(
+                f"backend {spec!r}: a device index is a non-negative "
+                "integer, as in 'jax:1'"
+            )
+        return JaxDeviceUnit(name, int(index))
     aliases = {
         "inline": InlineUnit,
         "thread": ThreadUnit, "threads": ThreadUnit,
         "process": ProcessPoolUnit, "processes": ProcessPoolUnit,
-        "jax": JaxDeviceUnit,
     }
     cls = aliases.get(text)
     if cls is None:

@@ -595,7 +595,10 @@ class RemoteWorker:
                     start_beater = True
             if start_beater:
                 self._beater.start()
-        self._send({"kind": "ready", "unit": name})
+        # the hosted unit's platform travels back, so a "jax" unit in a
+        # CPU-only worker is reported as the host computer it is
+        self._send({"kind": "ready", "unit": name,
+                    "platform": self._units[name].platform})
 
     def _handle_register(self, frame: dict) -> None:
         fn_id, fn = frame.get("fn_id"), frame.get("fn")
@@ -893,7 +896,8 @@ class RemoteUnit(BackendUnit):
     :class:`SocketTransport` at ``start``; reconnects on restart) or an
     already-connected ``transport=`` endpoint (loopback tests; single
     session).  ``remote_backend`` names the backend the worker hosts for
-    this unit ("thread" by default).
+    this unit ("thread" by default).  :attr:`platform` is the hosted
+    unit's, as the worker reports it in its ``ready`` frame.
 
     Dispatch fast path knobs:
 
@@ -1126,6 +1130,7 @@ class RemoteUnit(BackendUnit):
                 continue
             kind = frame.get("kind")
             if kind == "ready" and frame.get("unit") == self.name:
+                self.platform = frame.get("platform", "cpu")
                 return
             if kind == "error" and frame.get("unit") == self.name:
                 raise TransportError(
@@ -1533,13 +1538,19 @@ def spawn_worker(*, host: str = "127.0.0.1",
     work functions unpickle by module reference on the far side — the
     worker must be able to import whatever module defines them (test
     modules included).
+
+    The worker always runs JAX on the CPU (``JAX_PLATFORMS=cpu``, whatever
+    the parent's environment says): an accelerator belongs to one process
+    at a time, and the parent that spawns workers holds it.  A ``jax``
+    unit hosted by a worker therefore computes on the host and reports
+    ``platform == "cpu"``.
     """
     src_dir = os.path.dirname(os.path.dirname(os.path.dirname(
         os.path.abspath(__file__))))
     env = dict(os.environ)
     paths = [src_dir] + [p for p in sys.path if p and p != src_dir]
     env["PYTHONPATH"] = os.pathsep.join(paths)
-    env.setdefault("JAX_PLATFORMS", "cpu")
+    env["JAX_PLATFORMS"] = "cpu"
     entry = ("import sys; from repro.core.transport import _main; "
              "sys.exit(_main(sys.argv[1:]))")
     proc = subprocess.Popen(

@@ -7,5 +7,6 @@
                     path whose score-block HBM traffic dominates §Roofline)
 
 All kernels: pl.pallas_call + explicit BlockSpec VMEM tiling, ops.py jit'd
-wrapper, ref.py pure-jnp oracle, validated with interpret=True on CPU.
+wrapper, ref.py pure-jnp oracle.  Kernels run compiled on a TPU and in the Pallas
+interpreter on the CPU (:mod:`.platform`), where the tests validate them.
 """

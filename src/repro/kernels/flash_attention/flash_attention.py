@@ -27,6 +27,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from ..platform import resolve_interpret
+
 __all__ = ["flash_attention_pallas"]
 
 _NEG_INF = -0.7 * float(jnp.finfo(jnp.float32).max)
@@ -92,7 +94,7 @@ def flash_attention_pallas(
     scale: Optional[float] = None,
     q_block: int = 128,
     kv_block: int = 128,
-    interpret: bool = True,
+    interpret: Optional[bool] = None,
 ) -> jax.Array:
     b, sq, h, d = q.shape
     _, sk, kvh, _ = k.shape
@@ -128,7 +130,7 @@ def flash_attention_pallas(
             (1, 1, 1, g * q_block, d), lambda ib, ih, iq: (ib, ih, iq, 0, 0)
         ),
         out_shape=jax.ShapeDtypeStruct((b, kvh, n_q, g * q_block, d), q.dtype),
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(qr, kr, vr)
 
     # undo layout: (B,KVH,n_q,G,qb,D) → (B,Sq,H,D)

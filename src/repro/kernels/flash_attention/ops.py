@@ -28,7 +28,7 @@ __all__ = ["flash_attention", "kernel_hbm_bytes", "kernel_flops"]
 def flash_attention(
     q: jax.Array, k: jax.Array, v: jax.Array, *,
     causal: bool = True, window: int = 0,
-    q_block: int = 128, kv_block: int = 128, interpret: bool = True,
+    q_block: int = 128, kv_block: int = 128, interpret: bool | None = None,
 ) -> jax.Array:
     b, sq, h, d = q.shape
     sk = k.shape[1]

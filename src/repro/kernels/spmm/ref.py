@@ -108,34 +108,34 @@ def to_block_ell(p: SpmmProblem, *, k_cap: int = 0) -> BlockEll:
 
     ``k_cap`` bounds column blocks per row block (the ACC chunk-capacity
     knob); overflowing blocks are DROPPED here — the hybrid executor routes
-    such rows to the gather path instead, ENEAC-style.
+    such rows to the gather path instead, ENEAC-style.  Occupied column
+    blocks are kept in ascending order.  Vectorized over the nonzeros, so
+    a 29957-row matrix with ~6M nonzeros packs in seconds.
     """
     R = p.rows
-    rpad = (ROW_BLOCK - R % ROW_BLOCK) % ROW_BLOCK
-    n_rb = (R + rpad) // ROW_BLOCK
-    cpad_cols = ((p.n_cols + COL_BLOCK - 1) // COL_BLOCK) * COL_BLOCK
-
-    blocks = [dict() for _ in range(n_rb)]
-    for r in range(R):
-        rb, ri = divmod(r, ROW_BLOCK)
-        k = int(p.nnz[r])
-        for j in range(k):
-            c = int(p.cols[r, j])
-            cb, ci = divmod(c, COL_BLOCK)
-            blk = blocks[rb].setdefault(cb, np.zeros((ROW_BLOCK, COL_BLOCK), np.float32))
-            blk[ri, ci] += p.vals[r, j]
-
-    K = max((len(b) for b in blocks), default=1) or 1
+    n_rb = -(-R // ROW_BLOCK)
+    n_cb = -(-p.n_cols // COL_BLOCK)
+    live = np.arange(p.vals.shape[1])[None, :] < p.nnz[:, None]
+    r = np.nonzero(live)[0]
+    c = p.cols[live].astype(np.int64)
+    v = p.vals[live]
+    rb, ri = np.divmod(r, ROW_BLOCK)
+    cb, ci = np.divmod(c, COL_BLOCK)
+    blocks, inv = np.unique(rb * n_cb + cb, return_inverse=True)
+    b_rb, b_cb = np.divmod(blocks, n_cb)
+    occupied = np.bincount(b_rb, minlength=n_rb)
+    # rank of each occupied block within its row block (blocks are sorted)
+    rank = np.arange(len(blocks)) - np.searchsorted(b_rb, b_rb)
+    K = max(int(occupied.max(initial=0)), 1)
     if k_cap:
         K = min(K, k_cap)
     vals = np.zeros((n_rb, K, ROW_BLOCK, COL_BLOCK), np.float32)
     colblocks = np.zeros((n_rb, K), np.int32)
-    counts = np.zeros((n_rb,), np.int32)
-    for rb, b in enumerate(blocks):
-        items = sorted(b.items())[:K]
-        counts[rb] = len(items)
-        for k_, (cb, blk) in enumerate(items):
-            colblocks[rb, k_] = cb
-            vals[rb, k_] = blk
-    return BlockEll(vals=vals, colblocks=colblocks, counts=counts,
-                    rows=R, n_cols=cpad_cols)
+    keep = rank < K
+    colblocks[b_rb[keep], rank[keep]] = b_cb[keep]
+    k_nz = rank[inv]
+    sel = k_nz < K
+    np.add.at(vals, (rb[sel], k_nz[sel], ri[sel], ci[sel]), v[sel])
+    return BlockEll(vals=vals, colblocks=colblocks,
+                    counts=np.minimum(occupied, K).astype(np.int32),
+                    rows=R, n_cols=n_cb * COL_BLOCK)

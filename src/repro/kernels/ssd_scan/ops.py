@@ -13,7 +13,7 @@ __all__ = ["ssd_scan", "ssd_ref"]
 
 
 @functools.partial(jax.jit, static_argnames=("chunk", "interpret"))
-def ssd_scan(x, log_a, Bm, Cm, *, chunk: int = 64, interpret: bool = True):
+def ssd_scan(x, log_a, Bm, Cm, *, chunk: int = 64, interpret: bool | None = None):
     """(y, final_state) — Pallas path; falls back to the oracle when the
     sequence doesn't tile."""
     if x.shape[1] % chunk:

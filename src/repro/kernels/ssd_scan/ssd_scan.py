@@ -21,10 +21,13 @@ the (P, N) state, exactly like the flash kernel carries (m, l, acc).
 from __future__ import annotations
 
 import functools
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+
+from ..platform import resolve_interpret
 
 __all__ = ["ssd_scan_pallas"]
 
@@ -71,7 +74,7 @@ def ssd_scan_pallas(
     Cm: jax.Array,      # (B, S, N)
     *,
     chunk: int = 64,
-    interpret: bool = True,
+    interpret: Optional[bool] = None,
 ):
     b, s, h, p = x.shape
     n = Bm.shape[-1]
@@ -97,6 +100,6 @@ def ssd_scan_pallas(
             jax.ShapeDtypeStruct((b, h, s, p), jnp.float32),
             jax.ShapeDtypeStruct((b, h, p, n), jnp.float32),
         ],
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(xr, lar, Bm, Cm)
     return y.transpose(0, 2, 1, 3), h_final

@@ -12,7 +12,7 @@ from __future__ import annotations
 
 from typing import Optional, Tuple
 
-import jax
+from ..parallel.compat import make_mesh
 
 __all__ = ["make_production_mesh", "make_test_mesh", "HardwareSpec", "TPU_V5E"]
 
@@ -20,12 +20,12 @@ __all__ = ["make_production_mesh", "make_test_mesh", "HardwareSpec", "TPU_V5E"]
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return make_mesh(shape, axes)
 
 
 def make_test_mesh(shape: Tuple[int, ...] = (2, 2), axes: Tuple[str, ...] = ("data", "model")):
     """Small mesh for CPU tests (requires host-device-count override)."""
-    return jax.make_mesh(shape, axes)
+    return make_mesh(shape, axes)
 
 
 class HardwareSpec:

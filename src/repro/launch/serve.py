@@ -15,6 +15,7 @@ import numpy as np
 from ..configs import get_config
 from ..models import make_model
 from ..serving import Request, ServingEngine
+from .compile_cache import configure_compile_cache
 
 
 def main() -> None:
@@ -27,6 +28,7 @@ def main() -> None:
     ap.add_argument("--mode", choices=("continuous", "static"), default="continuous")
     ap.add_argument("--full", action="store_true", help="full (non-smoke) config")
     args = ap.parse_args()
+    configure_compile_cache()
 
     cfg = get_config(args.arch)
     if not args.full:

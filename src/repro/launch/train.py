@@ -36,7 +36,9 @@ from ..data import Prefetcher, SyntheticTokens
 from ..models import make_model
 from ..optim import AdamW, warmup_cosine
 from ..checkpoint import Checkpointer
+from ..parallel.compat import make_mesh
 from ..parallel.mesh_rules import MeshRules
+from .compile_cache import configure_compile_cache
 from .steps import make_train_step
 
 __all__ = ["TrainLoopConfig", "run_training"]
@@ -68,8 +70,7 @@ def run_training(cfg: TrainLoopConfig, *, mesh=None) -> Dict[str, float]:
 
     if mesh is None:
         n = len(jax.devices())
-        mesh = jax.make_mesh((n, 1), ("data", "model")) if n > 1 else \
-            jax.make_mesh((1, 1), ("data", "model"))
+        mesh = make_mesh((n, 1), ("data", "model"))
     rules = MeshRules(mesh, model_cfg.parallel)
 
     optimizer = AdamW(
@@ -165,6 +166,7 @@ def main() -> None:
     ap.add_argument("--resume", action="store_true")
     ap.add_argument("--microbatches", type=int, default=1)
     args = ap.parse_args()
+    configure_compile_cache()
     out = run_training(TrainLoopConfig(
         arch=args.arch, steps=args.steps, global_batch=args.global_batch,
         seq_len=args.seq_len, lr=args.lr, smoke=args.smoke,

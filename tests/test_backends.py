@@ -15,8 +15,8 @@ concurrency:
 * kernels driven through ``parallel_for(space=TiledSpace,
   backend="threads")`` produce bit-exact results — thread dispatch can
   never silently reorder or corrupt tile writes,
-* ``JaxDeviceUnit`` degrades cleanly to thread execution when jax is
-  absent.
+* ``"jax:<i>"`` binds ``JaxDeviceUnit`` to ``jax.devices()[i]`` and never
+  wraps an index that does not exist.
 
 Everything here runs on a real WallClock with microsecond-scale sleeps,
 so the whole module stays fast; the heavy randomized sweeps are marked
@@ -161,17 +161,21 @@ class TestUnits:
             unit, [Chunk(0, 8, "d0")],
             lambda c: f(jnp.arange(c.size, dtype=jnp.float32)),
         )
-        assert not unit.degraded
         assert float(recs[0].result) == float(sum(2.0 * i for i in range(8)))
 
-    def test_jax_unit_degrades_cleanly_without_jax(self, monkeypatch):
-        # the ISSUE acceptance: no jax -> ThreadUnit semantics, not a crash
-        monkeypatch.setattr(backends_mod, "_jax_module", lambda: None)
-        unit = JaxDeviceUnit("d0")
-        recs = self._drive(unit, [Chunk(0, 6, "d0")], lambda c: c.size)
-        assert unit.degraded
-        assert recs[0].result == 6 and recs[0].error is None
-        assert len(unit.dispatch_latencies) == 1
+    def test_jax_index_binds_that_device_and_posts_results_there(self):
+        import jax
+        import jax.numpy as jnp
+
+        unit = make_backend("jax:0", "d0")
+        assert isinstance(unit, JaxDeviceUnit)
+        assert unit.device == jax.devices()[0]
+        assert unit.platform == jax.devices()[0].platform
+        recs = self._drive(unit, [Chunk(0, 6, "d0")],
+                           lambda c: jnp.arange(c.size) * 2)
+        assert recs[0].error is None
+        assert recs[0].result.devices() == {jax.devices()[0]}
+        assert list(map(int, recs[0].result)) == [0, 2, 4, 6, 8, 10]
 
     def test_unknown_backend_spec_rejected(self):
         rt = HeteroRuntime()
@@ -269,38 +273,21 @@ class TestProcessPoolErrors:
 
 
 # ---------------------------------------------------------------------------
-# JaxDeviceUnit degradation (ISSUE 5 satellite): without jax, behaviour is
-# bit-identical to a ThreadUnit — same coverage, same report fields, same
-# exact-once side effects
+# JaxDeviceUnit device binding: an index that does not exist is an error,
+# never a silent wrap to device 0
 # ---------------------------------------------------------------------------
-class TestJaxDegradationParity:
-    def _run(self, backend_spec):
-        rec = Recorder()
+class TestJaxDeviceIndex:
+    def test_out_of_range_jax_index_raises(self):
+        import jax
+
+        n = len(jax.devices())
+        with pytest.raises(ValueError, match=f"device index {n} does not exist"):
+            make_backend(f"jax:{n}", "d0")
         rt = HeteroRuntime()
-        rt.register_unit("u0", WorkerKind.CC, work_fn=rec,
-                         backend=backend_spec)
-        # a fixed pre-split makes the run fully deterministic, so the two
-        # backends can be compared field-for-field, not just in aggregate
-        rep = rt.parallel_for(num_items=96, policy={"u0": (0, 96)},
-                              engine="interrupt")
-        return rep, rec
-
-    def test_no_jax_degrades_bit_identically_to_thread(self, monkeypatch):
-        monkeypatch.setattr(backends_mod, "_jax_module", lambda: None)
-        probe = JaxDeviceUnit("probe")
-        probe.start(CompletionBus())
-        assert probe.degraded, "monkeypatched import must trigger degradation"
-        probe.close()
-
-        rep_jax, rec_jax = self._run("jax")
-        rep_thr, rec_thr = self._run("thread")
-        assert rec_jax.counts == rec_thr.counts
-        for field in ("items", "chunks", "coverage", "per_worker_items",
-                      "per_worker_chunks"):
-            assert getattr(rep_jax, field) == getattr(rep_thr, field), field
-        assert set(rep_jax.dispatch_latency) == set(rep_thr.dispatch_latency)
-        # neither path has a transport in it
-        assert rep_jax.wire_latency is None and rep_thr.wire_latency is None
+        with pytest.raises(ValueError, match="does not exist"):
+            rt.register_unit("d0", WorkerKind.ACC, backend=f"jax:{n + 3}")
+        with pytest.raises(ValueError, match="non-negative integer"):
+            make_backend("jax:-1", "d0")
 
 
 # ---------------------------------------------------------------------------

@@ -16,9 +16,9 @@ PROGRAM = textwrap.dedent("""
     import jax, jax.numpy as jnp
     from jax.sharding import PartitionSpec as P
     from repro.parallel.collectives import compressed_psum
-    from repro.parallel.compat import shard_map
+    from repro.parallel.compat import make_mesh, shard_map
 
-    mesh = jax.make_mesh((8,), ("data",))
+    mesh = make_mesh((8,), ("data",))
     x = jax.random.normal(jax.random.PRNGKey(0), (8, 64, 32)) * 0.01
 
     def f(xb):

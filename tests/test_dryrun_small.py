@@ -17,11 +17,12 @@ from repro.launch.hlo_analysis import analyze_hlo
 from repro.launch.steps import make_decode_step, make_prefill_step, make_train_step
 from repro.models import make_model
 from repro.optim import AdamW
+from repro.parallel.compat import make_mesh
 from repro.parallel.mesh_rules import MeshRules
 
 
 def _mesh():
-    return jax.make_mesh((1, 1), ("data", "model"))
+    return make_mesh((1, 1), ("data", "model"))
 
 
 class TestLowerCompile:
@@ -82,7 +83,7 @@ class TestHloAnalysis:
         assert L in rep.trip_counts.values()
 
     def test_collective_detection(self):
-        mesh = jax.make_mesh((1, 1), ("data", "model"))
+        mesh = make_mesh((1, 1), ("data", "model"))
         from jax.sharding import NamedSharding, PartitionSpec as P
 
         def f(x):

@@ -18,9 +18,10 @@ PROGRAM = textwrap.dedent("""
     sys.path.insert(0, %r)
     import jax, jax.numpy as jnp
     import numpy as np
+    from repro.parallel.compat import make_mesh
     from repro.parallel.pipeline import pipeline_apply, stage_partition
 
-    mesh = jax.make_mesh((4, 2), ("pod", "model"))
+    mesh = make_mesh((4, 2), ("pod", "model"))
     L, d, n_micro, B = 8, 16, 6, 4
     key = jax.random.PRNGKey(0)
     params = {

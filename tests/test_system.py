@@ -12,6 +12,7 @@ from repro.core.parallel_for import HybridExecutor
 from repro.launch.train import TrainLoopConfig, run_training
 from repro.models import make_model
 from repro.optim import AdamW
+from repro.parallel.compat import make_mesh
 from repro.parallel.mesh_rules import MeshRules
 from repro.launch.steps import make_train_step
 
@@ -40,7 +41,7 @@ class TestTrainingLoop:
         """Grad accumulation is numerically equivalent to one big batch."""
         cfg = get_config("tinyllama-1.1b").smoke()
         model = make_model(cfg)
-        mesh = jax.make_mesh((1, 1), ("data", "model"))
+        mesh = make_mesh((1, 1), ("data", "model"))
         rules = MeshRules(mesh, cfg.parallel)
         shape = InputShape("t", 32, 8, "train")
         params = model.init(jax.random.PRNGKey(0))

@@ -361,7 +361,7 @@ class TestSocketTransport:
             tr = SocketTransport.connect(server.address, timeout=5.0)
             tr.send({"kind": "hello", "unit": "u0", "backend": "inline"})
             frame = tr.recv(timeout=5.0)
-            assert frame == {"kind": "ready", "unit": "u0"}
+            assert frame == {"kind": "ready", "unit": "u0", "platform": "cpu"}
             tr.close()
         finally:
             server.stop()
@@ -1252,6 +1252,13 @@ def _sleepy_noop(chunk):
     time.sleep(chunk.size * 2e-3)
 
 
+def _result_platforms(chunk):
+    """Module-level so a worker can unpickle it: where jnp computed."""
+    import jax.numpy as jnp
+
+    return sorted(d.platform for d in jnp.arange(chunk.size).devices())
+
+
 def read_results(root) -> bytes:
     names = sorted(n for n in os.listdir(root) if n.endswith(".bin"))
     return b"".join(
@@ -1312,6 +1319,27 @@ class TestSubprocessWorkers:
         # the dispatch-latency split is populated for the remote run only
         assert set(rep_remote.wire_latency) == {"s0/r0", "s1/r1"}
         assert rep_inline.wire_latency is None
+
+    def test_worker_jax_unit_is_a_cpu_unit_whatever_the_parent_says(
+            self, monkeypatch):
+        # the chip belongs to the parent: a spawned worker runs jax on the
+        # CPU even when the parent's environment names an accelerator, and
+        # its "jax" unit reports the platform it really computes on
+        monkeypatch.setenv("JAX_PLATFORMS", "tpu")
+        handle = spawn_worker()
+        unit = RemoteUnit("j0", address=handle.address, remote_backend="jax")
+        bus = CompletionBus()
+        try:
+            unit.start(bus)
+            assert unit.platform == "cpu"
+            unit.submit(Chunk(0, 3, "j0"), _result_platforms)
+            assert bus.wait(timeout=60.0)
+            rec = bus.drain()[0]
+            assert rec.error is None, rec.error
+            assert rec.result == ["cpu"]
+        finally:
+            unit.close()
+            handle.terminate()
 
     def test_killed_worker_subprocess_does_not_hang_the_run(self):
         handle = spawn_worker()
