@@ -60,6 +60,7 @@ from typing import Any, Callable, Dict, List, Mapping, Optional, Sequence, Tuple
 
 from .elastic import ElasticEvent
 from .scheduler import Chunk
+from .trace import ChunkTimes, Phases, Timeline, span
 
 __all__ = [
     "BackendUnit",
@@ -130,9 +131,17 @@ class CompletionRecord:
     unit: str
     chunk: Chunk
     elapsed: float               # execution time (dispatch -> result ready)
-    dispatch_latency: float      # submit() -> execution actually starting
+    dispatch_latency: float      # submit() -> execution starting; the
+                                 # enqueue call itself on a JaxDeviceUnit
     error: Optional[BaseException] = None
     result: Any = None           # work_fn return value (serving uses this)
+    # perf_counter_ns stamps for RunReport.timeline (ChunkTimes), from the
+    # readings above: set by the local backends, 0 on transport units;
+    # posted_ns by _post
+    submitted_ns: int = 0
+    enqueued_ns: int = 0
+    ready_ns: int = 0
+    posted_ns: int = 0
 
 
 class CompletionBus:
@@ -216,6 +225,11 @@ class CompletionBus:
         return out
 
 
+def _ns(t: float) -> int:
+    """A ``time.perf_counter()`` reading as ``perf_counter_ns`` (one clock)."""
+    return round(t * 1e9)
+
+
 class BackendUnit:
     """Protocol + shared bookkeeping for one asynchronously-driven unit.
 
@@ -265,20 +279,24 @@ class BackendUnit:
     def _post(self, rec: CompletionRecord) -> None:
         assert self._bus is not None, f"unit {self.name!r} not started"
         self.dispatch_latencies.append(rec.dispatch_latency)
+        rec.posted_ns = time.perf_counter_ns()
         self._bus.post(rec)
 
     def _execute(self, chunk: Chunk, work_fn: WorkFn, submitted: float) -> None:
         """Run one chunk synchronously and post the completion."""
-        t_start = time.perf_counter()
         result, error = None, None
-        try:
-            result = work_fn(chunk)
-        except BaseException as exc:
-            error = exc
-        t_end = time.perf_counter()
+        # the stamps sit inside the span, so elapsed times the work alone
+        with span("eneac.unit_exec", unit=self.name, start=chunk.start):
+            t_start = time.perf_counter()
+            try:
+                result = work_fn(chunk)
+            except BaseException as exc:
+                error = exc
+            t_end = time.perf_counter()
         self._post(CompletionRecord(
             unit=self.name, chunk=chunk, elapsed=t_end - t_start,
             dispatch_latency=t_start - submitted, error=error, result=result,
+            submitted_ns=_ns(submitted), enqueued_ns=_ns(t_start), ready_ns=_ns(t_end),
         ))
 
     def describe(self) -> str:
@@ -426,9 +444,12 @@ class ProcessPoolUnit(BackendUnit):
             except BaseException as exc:
                 error = exc
                 elapsed = time.perf_counter() - submitted
+            started = submitted + lat    # in the worker, on the same clock
             self._post(CompletionRecord(
                 unit=self.name, chunk=chunk, elapsed=elapsed,
                 dispatch_latency=lat, error=error, result=result,
+                submitted_ns=_ns(submitted), enqueued_ns=_ns(started),
+                ready_ns=_ns(started + elapsed),
             ))
 
         fut.add_done_callback(on_done)
@@ -506,16 +527,18 @@ class JaxDeviceUnit(BackendUnit):
             if item is None:
                 return
             submitted, dispatched, chunk, out, error = item
-            if error is None:
-                try:
-                    self._jax.block_until_ready(out)
-                except BaseException as exc:
-                    error = exc
-            t_end = time.perf_counter()
+            with span("eneac.acc_wait", unit=self.name, start=chunk.start):
+                if error is None:
+                    try:
+                        self._jax.block_until_ready(out)
+                    except BaseException as exc:
+                        error = exc
+                t_end = time.perf_counter()    # before the span closes
             self._post(CompletionRecord(
                 unit=self.name, chunk=chunk, elapsed=t_end - dispatched,
                 dispatch_latency=dispatched - submitted, error=error,
-                result=out,
+                result=out, submitted_ns=_ns(submitted),
+                enqueued_ns=_ns(dispatched), ready_ns=_ns(t_end),
             ))
 
     def submit(self, chunk: Chunk, work_fn: WorkFn) -> None:
@@ -659,6 +682,10 @@ class BackendEngine:
     invariant carries over unchanged.  At most one quarantine per unit
     per run; the last active unit is never quarantined (slow coverage
     beats no coverage).  Recorded as an ``action="straggler"`` event.
+
+    ``phases`` times the dispatcher's phases (and opens their ``eneac.*``
+    spans); :meth:`timeline` returns them with one
+    :class:`~repro.core.trace.ChunkTimes` per completed chunk.
     """
 
     def __init__(
@@ -672,6 +699,7 @@ class BackendEngine:
         default_fn: Optional[WorkFn] = None,
         join_backend: Optional[Callable[[ElasticEvent], BackendUnit]] = None,
         straggler=None,
+        phases: Optional[Phases] = None,
     ) -> None:
         self.sched = sched
         self.fns: Dict[str, Optional[WorkFn]] = dict(fns)
@@ -691,6 +719,10 @@ class BackendEngine:
         self._straggled: set = set()
         self._errors: List[BaseException] = []
         self._t0 = 0.0
+        self.phases = phases if phases is not None else Phases()
+        self._chunk_times: List[ChunkTimes] = []
+        self._wakeups = 0
+        self._drained = 0
 
     # -- helpers ------------------------------------------------------------
     def _now(self) -> float:
@@ -729,11 +761,13 @@ class BackendEngine:
         while self._inflight.get(name, 0) < cap:
             if self._errors:
                 break
-            chunk = self.sched.next_chunk(name, now=time.perf_counter())
-            if chunk is None:
-                break
-            self._inflight[name] = self._inflight.get(name, 0) + 1
-            self.units[name].submit(chunk, self.fns[name])
+            with self.phases("submit", unit=name) as sp:
+                chunk = self.sched.next_chunk(name, now=time.perf_counter())
+                if chunk is None:
+                    break
+                sp.set_metadata(start=chunk.start)
+                self._inflight[name] = self._inflight.get(name, 0) + 1
+                self.units[name].submit(chunk, self.fns[name])
             issued = True
         if issued:
             self.units[name].flush()
@@ -822,6 +856,8 @@ class BackendEngine:
         })
 
     def _process_completions(self, recs: List[CompletionRecord]) -> None:
+        drained = time.perf_counter_ns()
+        self._drained += len(recs)
         for rec in recs:
             if isinstance(rec.error, WorkerLost):
                 self._lose_unit(rec)
@@ -836,6 +872,10 @@ class BackendEngine:
             else:
                 self._inflight.pop(rec.unit, None)
             self.sched.complete(rec.unit, rec.elapsed, chunk=rec.chunk)
+            if rec.submitted_ns:
+                self._chunk_times.append(ChunkTimes(
+                    rec.unit, rec.chunk.start, rec.chunk.stop, rec.submitted_ns,
+                    rec.enqueued_ns, rec.ready_ns, rec.posted_ns, drained))
             if rec.error is not None:
                 self._errors.append(rec.error)
             if rec.unit in self._leaving and not self._inflight.get(rec.unit, 0):
@@ -887,12 +927,13 @@ class BackendEngine:
         """Drive the space to completion; returns the wall makespan."""
         self._t0 = time.perf_counter()
         set_cap = getattr(self.sched, "set_capacity", None)
-        for name, unit in self.units.items():
-            unit.start(self.bus)
-            self._own_units.add(name)
-            self._last_caps[name] = self._capacity(name)
-            if set_cap is not None:
-                set_cap(name, self._last_caps[name])
+        with self.phases("units_start"):
+            for name, unit in self.units.items():
+                unit.start(self.bus)
+                self._own_units.add(name)
+                self._last_caps[name] = self._capacity(name)
+                if set_cap is not None:
+                    set_cap(name, self._last_caps[name])
         try:
             self._apply_due_events()
             self._dispatch_idle()
@@ -901,9 +942,12 @@ class BackendEngine:
                     timeout = None
                     if self.pending:
                         timeout = max(self.pending[0].t - self._now(), 0.0)
-                    self.bus.wait(timeout=timeout)
-                    self._apply_due_events()
-                    self._process_completions(self.bus.drain())
+                    with self.phases("bus_wait"):
+                        self.bus.wait(timeout=timeout)
+                    self._wakeups += 1
+                    with self.phases("complete"):
+                        self._apply_due_events()
+                        self._process_completions(self.bus.drain())
                     self._dispatch_idle()
                     continue
                 # nothing in flight: either more work is dispatchable, or
@@ -921,15 +965,24 @@ class BackendEngine:
                     continue
                 break
         finally:
-            for name, unit in self.units.items():
-                if name in self._own_units:
-                    unit.close()
+            with self.phases("units_close"):
+                for name, unit in self.units.items():
+                    if name in self._own_units:
+                        unit.close()
         if self._errors:
             raise self._errors[0]
         return time.perf_counter() - self._t0
 
+    def timeline(self) -> Timeline:
+        """The run's :class:`~repro.core.trace.Timeline`."""
+        return Timeline(
+            phase_s=self.phases.seconds, chunks=self._chunk_times,
+            wakeups=self._wakeups, drained=self._drained,
+        )
+
     def dispatch_latency(self) -> Dict[str, float]:
-        """Mean submit->execution latency per unit, in seconds."""
+        """Mean dispatch latency per unit, in seconds (see
+        :attr:`CompletionRecord.dispatch_latency`)."""
         out: Dict[str, float] = {}
         for name, unit in self._all_units.items():
             lats = unit.dispatch_latencies

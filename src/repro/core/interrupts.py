@@ -34,6 +34,7 @@ from dataclasses import dataclass, field
 from typing import Callable, Dict, List, Optional
 
 from .scheduler import Chunk, MultiDynamicScheduler
+from .trace import Timeline
 
 __all__ = ["CompletionEvent", "AsyncEngine", "PollingEngine", "RunReport"]
 
@@ -71,6 +72,9 @@ class RunReport:
     chunks: int
     per_worker_items: Dict[str, int]
     per_worker_chunks: Dict[str, int]
+    # Wall-clock interrupt runs: seconds from a chunk's execution start to
+    # its result, summed per unit.  On a JaxDeviceUnit that runs from the
+    # enqueue call's return to ready, so it includes queueing on the device.
     per_worker_busy: Dict[str, float]
     load_balance: float
     # Sorted (start, stop) spans of completed chunks; filled by
@@ -82,10 +86,12 @@ class RunReport:
     # Per-shard sub-reports when the run iterated a ShardedSpace; unit keys
     # in the merged per_worker_* maps are prefixed "s{shard}/".
     shard_reports: Optional[List["RunReport"]] = None
-    # Mean submit->execution-start latency per unit in seconds, measured by
-    # the backend layer (wall-clock interrupt runs only; None otherwise).
-    # Low values with overlapping busy times are what "real asynchrony"
-    # looks like: the dispatcher never sits between a free unit and work.
+    # Mean dispatch latency per unit in seconds, measured by the backend
+    # layer (wall-clock interrupt runs only; None otherwise): submit ->
+    # execution start on host units, and on a JaxDeviceUnit the enqueue
+    # call itself (the device may start the work later).  Low values with
+    # overlapping busy times are what "real asynchrony" looks like: the
+    # dispatcher never sits between a free unit and work.
     dispatch_latency: Optional[Dict[str, float]] = None
     # The wire + remote-queue component of dispatch_latency for units that
     # executed behind a transport (repro.core.transport.RemoteUnit): mean
@@ -106,6 +112,10 @@ class RunReport:
     # wire transit vs. per-chunk service time, re-evaluated at flush
     # boundaries).  None when no transport unit took part in the run.
     batch_frames: Optional[Dict[str, int]] = None
+    # The dispatcher's phases, one ChunkTimes per completed chunk and the
+    # completion bus's wake-ups (repro.core.trace.Timeline): wall-clock
+    # "interrupt" runs over a flat space only; None otherwise.
+    timeline: Optional[Timeline] = None
 
     @property
     def throughput(self) -> float:

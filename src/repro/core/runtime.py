@@ -84,6 +84,7 @@ from .scheduler import (
 )
 from .space import FlatSpace, IterationSpace, ShardedSpace, TiledSpace, as_space
 from .straggler import StragglerDetector
+from .trace import Phases, span
 
 __all__ = [
     "HeteroRuntime",
@@ -688,118 +689,122 @@ class HeteroRuntime:
         an ``action="straggler"`` event.  The last active unit is never
         quarantined.
         """
-        if work_fn is not None and not callable(work_fn):
-            raise TypeError(
-                f"first argument is the work function, got {work_fn!r}; "
-                "pass the space size as num_items=N"
-            )
-        if engine not in ENGINES:
-            raise ValueError(f"unknown engine {engine!r} (want one of {ENGINES})")
-        if space is None and num_items <= 0:
-            raise ValueError(f"num_items must be positive, got {num_items}")
-        sp = as_space(space, num_items)
-        specs = self._resolve_units(units)
+        with span("eneac.parallel_for"):
+            if work_fn is not None and not callable(work_fn):
+                raise TypeError(
+                    f"first argument is the work function, got {work_fn!r}; "
+                    "pass the space size as num_items=N"
+                )
+            if engine not in ENGINES:
+                raise ValueError(f"unknown engine {engine!r} (want one of {ENGINES})")
+            if space is None and num_items <= 0:
+                raise ValueError(f"num_items must be positive, got {num_items}")
+            sp = as_space(space, num_items)
+            specs = self._resolve_units(units)
 
-        simulated = isinstance(self.clock, SimulatedClock)
-        elastic_events = self._normalize_elastic(elastic, specs)
-        if elastic_events and not simulated:
-            if engine != "interrupt":
+            simulated = isinstance(self.clock, SimulatedClock)
+            elastic_events = self._normalize_elastic(elastic, specs)
+            if elastic_events and not simulated:
+                if engine != "interrupt":
+                    raise ValueError(
+                        "elastic join/leave under a WallClock needs the "
+                        "event-driven 'interrupt' engine (serial polling/inline "
+                        "drivers cannot observe membership changes mid-chunk); "
+                        "use a SimulatedClock for deterministic serial replay"
+                    )
+                if any(ev.action == "join" for ev in elastic_events) and work_fn is None:
+                    raise ValueError(
+                        "wall-clock joins need an explicit work_fn argument "
+                        "(the joining unit has no registered one)"
+                    )
+            fns: Dict[str, Optional[WorkFn]] = {
+                s.name: (work_fn if work_fn is not None else s.work_fn) for s in specs
+            }
+            if not simulated:
+                missing = [n for n, f in fns.items() if f is None]
+                if missing:
+                    raise ValueError(
+                        f"units {missing} have no work_fn (required on a wall clock)"
+                    )
+                if item_cost is not None:
+                    raise ValueError("item_cost is only meaningful under SimulatedClock")
+            if isinstance(backend, BackendUnit) and len(specs) > 1:
                 raise ValueError(
-                    "elastic join/leave under a WallClock needs the "
-                    "event-driven 'interrupt' engine (serial polling/inline "
-                    "drivers cannot observe membership changes mid-chunk); "
-                    "use a SimulatedClock for deterministic serial replay"
+                    "a single BackendUnit instance cannot back multiple units; "
+                    "pass a backend spec string or register per-unit instances"
                 )
-            if any(ev.action == "join" for ev in elastic_events) and work_fn is None:
+            if item_cost is not None and len(item_cost) != sp.num_items:
                 raise ValueError(
-                    "wall-clock joins need an explicit work_fn argument "
-                    "(the joining unit has no registered one)"
+                    f"item_cost has {len(item_cost)} entries for {sp.num_items} items"
                 )
-        fns: Dict[str, Optional[WorkFn]] = {
-            s.name: (work_fn if work_fn is not None else s.work_fn) for s in specs
-        }
-        if not simulated:
-            missing = [n for n, f in fns.items() if f is None]
-            if missing:
-                raise ValueError(
-                    f"units {missing} have no work_fn (required on a wall clock)"
-                )
-            if item_cost is not None:
-                raise ValueError("item_cost is only meaningful under SimulatedClock")
-        if isinstance(backend, BackendUnit) and len(specs) > 1:
-            raise ValueError(
-                "a single BackendUnit instance cannot back multiple units; "
-                "pass a backend spec string or register per-unit instances"
-            )
-        if item_cost is not None and len(item_cost) != sp.num_items:
-            raise ValueError(
-                f"item_cost has {len(item_cost)} entries for {sp.num_items} items"
-            )
-        if straggler is not None:
-            if simulated:
-                raise ValueError(
-                    "straggler detection runs in the wall-clock BackendEngine; "
-                    "a SimulatedClock run has no real service times to watch "
-                    "— model slowdowns via item_cost/speed instead"
-                )
-            if engine != "interrupt":
-                raise ValueError(
-                    "straggler detection needs the event-driven 'interrupt' "
-                    "engine (serial drivers cannot quarantine mid-run)"
-                )
+            if straggler is not None:
+                if simulated:
+                    raise ValueError(
+                        "straggler detection runs in the wall-clock BackendEngine; "
+                        "a SimulatedClock run has no real service times to watch "
+                        "— model slowdowns via item_cost/speed instead"
+                    )
+                if engine != "interrupt":
+                    raise ValueError(
+                        "straggler detection needs the event-driven 'interrupt' "
+                        "engine (serial drivers cannot quarantine mid-run)"
+                    )
+                if isinstance(sp, ShardedSpace):
+                    raise ValueError(
+                        "one StragglerDetector cannot be shared by concurrent "
+                        "shard engines; run per-shard parallel_for calls with "
+                        "their own detectors instead"
+                    )
+
             if isinstance(sp, ShardedSpace):
-                raise ValueError(
-                    "one StragglerDetector cannot be shared by concurrent "
-                    "shard engines; run per-shard parallel_for calls with "
-                    "their own detectors instead"
-                )
-
-        if isinstance(sp, ShardedSpace):
-            if isinstance(policy, Mapping):
-                raise ValueError(
-                    "a fixed {unit: (start, stop)} policy is ambiguous over a "
-                    "ShardedSpace; use multidynamic/static/oracle"
-                )
-            if isinstance(backend, BackendUnit):
-                raise ValueError(
-                    "a single BackendUnit instance cannot back a ShardedSpace "
-                    "run (each shard engine needs its own workers); pass a "
-                    "backend spec string instead"
-                )
-            if isinstance(backend, str) and backend.startswith("remote:"):
-                raise ValueError(
-                    "a call-level remote backend would make every shard "
-                    "replicate its units onto one worker host; register "
-                    "per-unit remote backends and pin them via "
-                    "ShardedSpace(placement={unit: shard}) instead"
-                )
-            rep = self._run_sharded(
-                sp, specs, fns, work_fn, policy, engine, acc_chunk,
-                item_cost, poll_interval, scheduler_kwargs, elastic_events,
-                backend, kernel=kernel,
-            )
-        else:
-            sched = self._make_scheduler(
-                sp.num_items, specs, policy, acc_chunk, scheduler_kwargs,
-                kernel=kernel,
-            )
-            if simulated:
-                rep = self._run_simulated(
-                    sched, specs, fns, engine, sp.num_items, item_cost,
-                    poll_interval, clock=self.clock, elastic=elastic_events,
-                    expected=sp.num_items, default_fn=work_fn,
+                if isinstance(policy, Mapping):
+                    raise ValueError(
+                        "a fixed {unit: (start, stop)} policy is ambiguous over a "
+                        "ShardedSpace; use multidynamic/static/oracle"
+                    )
+                if isinstance(backend, BackendUnit):
+                    raise ValueError(
+                        "a single BackendUnit instance cannot back a ShardedSpace "
+                        "run (each shard engine needs its own workers); pass a "
+                        "backend spec string instead"
+                    )
+                if isinstance(backend, str) and backend.startswith("remote:"):
+                    raise ValueError(
+                        "a call-level remote backend would make every shard "
+                        "replicate its units onto one worker host; register "
+                        "per-unit remote backends and pin them via "
+                        "ShardedSpace(placement={unit: shard}) instead"
+                    )
+                rep = self._run_sharded(
+                    sp, specs, fns, work_fn, policy, engine, acc_chunk,
+                    item_cost, poll_interval, scheduler_kwargs, elastic_events,
+                    backend, kernel=kernel,
                 )
             else:
-                rep = self._run_wall(
-                    sched, specs, fns, engine, poll_interval,
-                    elastic=elastic_events, expected=sp.num_items,
-                    default_fn=work_fn, backend=backend, straggler=straggler,
-                )
-        if self.cost_model is not None:
-            # every run teaches the model — including multidynamic warmups,
-            # which is what lets a later policy="learned" run pre-split
-            self.cost_model.observe_report(rep, kernel)
-        return rep
+                phases = Phases()
+                with phases("report"):
+                    sched = self._make_scheduler(
+                        sp.num_items, specs, policy, acc_chunk, scheduler_kwargs,
+                        kernel=kernel,
+                    )
+                if simulated:
+                    rep = self._run_simulated(
+                        sched, specs, fns, engine, sp.num_items, item_cost,
+                        poll_interval, clock=self.clock, elastic=elastic_events,
+                        expected=sp.num_items, default_fn=work_fn,
+                    )
+                else:
+                    rep = self._run_wall(
+                        sched, specs, fns, engine, poll_interval,
+                        elastic=elastic_events, expected=sp.num_items,
+                        default_fn=work_fn, backend=backend, straggler=straggler,
+                        phases=phases,
+                    )
+            if self.cost_model is not None:
+                # every run teaches the model — including multidynamic warmups,
+                # which is what lets a later policy="learned" run pre-split
+                self.cost_model.observe_report(rep, kernel)
+            return rep
 
     @staticmethod
     def _normalize_elastic(
@@ -844,6 +849,7 @@ class HeteroRuntime:
         default_fn: Optional[WorkFn] = None,
         backend: Optional[Union[str, BackendUnit]] = None,
         straggler: Optional[StragglerDetector] = None,
+        phases: Optional[Phases] = None,
     ) -> RunReport:
         if engine == "interrupt":
             # Event-driven dispatch over real backend units: each unit's
@@ -851,21 +857,23 @@ class HeteroRuntime:
             # default), completions arrive on a condition variable, and
             # elastic membership changes apply between dispatches under
             # the tracked scheduler's lock.
-            units = {
-                s.name: make_backend(
-                    backend if backend is not None else s.backend, s.name
+            phases = phases if phases is not None else Phases()
+            with phases("units_start"):
+                units = {
+                    s.name: make_backend(
+                        backend if backend is not None else s.backend, s.name
+                    )
+                    for s in specs
+                }
+                eng = BackendEngine(
+                    sched, fns, units,
+                    expected=expected, elastic=elastic, default_fn=default_fn,
+                    join_backend=lambda ev: make_backend(
+                        backend if not isinstance(backend, BackendUnit) else None,
+                        ev.unit,
+                    ),
+                    straggler=straggler, phases=phases,
                 )
-                for s in specs
-            }
-            eng = BackendEngine(
-                sched, fns, units,
-                expected=expected, elastic=elastic, default_fn=default_fn,
-                join_backend=lambda ev: make_backend(
-                    backend if not isinstance(backend, BackendUnit) else None,
-                    ev.unit,
-                ),
-                straggler=straggler,
-            )
             wall = eng.run()
             # "dead" (heartbeat conviction) is as much a loss as "lost"
             # (EOF): either way a unit departed with work requeued, so an
@@ -878,11 +886,13 @@ class HeteroRuntime:
                     "completed but every remaining unit departed or lost "
                     "its worker"
                 )
-            rep = _build_report(sched, wall, dispatch=eng.dispatch_latency(),
-                                wire=eng.wire_latency(),
-                                batch_frames=eng.frame_batching())
-            if eng.events:
-                rep.events = eng.events
+            with phases("report"):
+                rep = _build_report(sched, wall, dispatch=eng.dispatch_latency(),
+                                    wire=eng.wire_latency(),
+                                    batch_frames=eng.frame_batching())
+                if eng.events:
+                    rep.events = eng.events
+            rep.timeline = eng.timeline()
         else:
             # "inline" is exactly the polling driver without the busy-wait
             # penalty: a deterministic serial round-robin on the caller

@@ -227,7 +227,6 @@ class MultiDynamicScheduler:
         # set_capacity() and may then keep several in flight.
         self._outstanding: Dict[str, List[Chunk]] = {}
         self._capacity: Dict[str, int] = {}
-        self._issue_times: Dict[str, float] = {}
         self._history: List[Tuple[Chunk, float]] = []
 
     def set_capacity(self, worker: str, capacity: int) -> None:
@@ -259,7 +258,6 @@ class MultiDynamicScheduler:
         with self._lock:
             state = self._workers.get(worker)
             chunks = self._outstanding.pop(worker, None)
-            self._issue_times.pop(worker, None)
             if state is not None:
                 state.busy = False
             return list(chunks) if chunks else []
@@ -340,7 +338,6 @@ class MultiDynamicScheduler:
             self._next += size
             state.busy = True
             self._outstanding.setdefault(worker, []).append(chunk)
-            self._issue_times[worker] = now
             return chunk
 
     def complete(self, worker: str, elapsed: float,
