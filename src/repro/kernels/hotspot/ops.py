@@ -17,7 +17,15 @@ and uses the full grid's coefficients:
   places it), so every ACC chunk, the last partial one included, runs the
   one compiled program.
 * :func:`hotspot_rows_host` — the CC path: the same update in numpy on
-  the host CPU, for any band size.
+  the host CPU, for any band size.  Its step is the update folded once
+  per call into ``t' = c0·t + cx·(left + right) + cy·(up + down) + q``,
+  with ``cx = k/Rx``, ``cy = k/Ry``, ``c0 = 1 − 2k/Rx − 2k/Ry − k/Rz`` and
+  ``q = k·P + k·T_amb/Rz`` (``k = dt/Cap``; coefficients in float64, cast
+  to float32 once): the same sum in another order.  Its halo shrinks by
+  one row a step: the last step computes only the band, each earlier one
+  the rows the next reads, so the band's rows stay exact.  It allocates
+  its scratch per call and only reads ``temp`` and ``power``, since four
+  CC threads call it at once beside the dispatcher, on one shared grid.
 """
 
 from __future__ import annotations
@@ -116,19 +124,57 @@ def hotspot_rows_host(
     temp: np.ndarray, power: np.ndarray, start: int, stop: int,
     cfg: HotspotConfig, steps: int,
 ) -> np.ndarray:
-    """Rows [start, stop) after ``steps`` steps, computed in numpy (f32)."""
+    """Rows [start, stop) after ``steps`` steps, computed in numpy (f32).
+
+    Each step is the folded update ``t' = c0·t + cx·(left + right) +
+    cy·(up + down) + q`` (module docstring), eight ufunc calls that each
+    write into a buffer allocated once per call.  The state lives in two
+    buffers of the band and its halo rows, padded by one row and column on
+    each side and swapped every step; the pads hold the clamped
+    neighbours, so no step concatenates or allocates.  Step ``s`` (from
+    0) computes only the rows within ``steps - 1 - s`` of the band: each
+    of them reads rows that the step before computed, so the band's own
+    rows are exact.  The range stops at the grid's own edge, where the
+    outer row repeats the edge row before each step, which is the
+    stencil's clamp.
+
+    A pure function of its arguments: ``temp`` and ``power`` are only
+    read, and every buffer is the call's own (the result is a view of
+    one), so the CC units call it from four threads at once, on one
+    shared grid.
+    """
     rows, cols = temp.shape
     cap, rx, ry, rz, dt = hotspot_coefficients(cfg, rows, cols)
+    k = dt / cap
     f32 = np.float32
-    k, rx, ry, rz, amb = f32(dt / cap), f32(rx), f32(ry), f32(rz), f32(cfg.amb_temp)
+    c0 = f32(1.0 - 2.0 * k / rx - 2.0 * k / ry - k / rz)
+    cx, cy = f32(k / rx), f32(k / ry)
     lo, hi = max(start - steps, 0), min(stop + steps, rows)
-    t = np.asarray(temp[lo:hi], f32)
-    p = np.asarray(power[lo:hi], f32)
-    for _ in range(steps):
-        up = np.concatenate([t[:1], t[:-1]], axis=0)
-        down = np.concatenate([t[1:], t[-1:]], axis=0)
-        left = np.concatenate([t[:, :1], t[:, :-1]], axis=1)
-        right = np.concatenate([t[:, 1:], t[:, -1:]], axis=1)
-        t = t + k * (p + (left + right - 2 * t) / rx
-                     + (up + down - 2 * t) / ry + (amb - t) / rz)
-    return t[start - lo:stop - lo]
+    n = hi - lo
+    # Buffer row i holds grid row lo + i - 1; column j holds column j - 1.
+    src, dst = np.empty((2, n + 2, cols + 2), f32)
+    part = np.empty((n, cols), f32)
+    q = np.multiply(power[lo:hi], f32(k), dtype=f32)
+    np.add(q, f32(k * cfg.amb_temp / rz), out=q)
+    src[1:n + 1, 1:cols + 1] = temp[lo:hi]
+    for s in range(steps):
+        reach = steps - 1 - s
+        r0, r1 = max(start - reach, 0), min(stop + reach, rows)
+        i0, i1 = r0 - lo + 1, r1 - lo + 1
+        if r0 == 0:
+            src[0, 1:-1] = src[1, 1:-1]
+        if r1 == rows:
+            src[i1, 1:-1] = src[i1 - 1, 1:-1]
+        src[i0:i1, 0] = src[i0:i1, 1]
+        src[i0:i1, -1] = src[i0:i1, -2]
+        out, tmp = dst[i0:i1, 1:-1], part[:i1 - i0]
+        np.add(src[i0:i1, :-2], src[i0:i1, 2:], out=out)
+        np.multiply(out, cx, out=out)
+        np.add(src[i0 - 1:i1 - 1, 1:-1], src[i0 + 1:i1 + 1, 1:-1], out=tmp)
+        np.multiply(tmp, cy, out=tmp)
+        np.add(out, tmp, out=out)
+        np.multiply(src[i0:i1, 1:-1], c0, out=tmp)
+        np.add(out, tmp, out=out)
+        np.add(out, q[i0 - 1:i1 - 1], out=out)
+        src, dst = dst, src
+    return src[start - lo + 1:stop - lo + 1, 1:-1]

@@ -1,6 +1,9 @@
 """Per-kernel validation: shape/dtype sweeps vs the ref.py jnp oracles
 (on the CPU, interpret=None runs the Pallas kernel bodies in the interpreter)."""
 
+import sys
+import threading
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -67,11 +70,11 @@ class TestHotspotBands:
 
     GRID, STEPS, CHUNK = 96, 3, 16
 
-    def _problem(self):
-        cfg = HotspotConfig(grid=self.GRID, iterations=self.GRID)
-        t0 = 80.0 + 10 * jax.random.uniform(KEY, (self.GRID, self.GRID))
-        p = jax.random.uniform(jax.random.PRNGKey(1), (self.GRID, self.GRID))
-        ref = np.asarray(hotspot_ref(t0, p, cfg, self.STEPS))
+    def _problem(self, grid=GRID, steps=STEPS):
+        cfg = HotspotConfig(grid=grid, iterations=grid)
+        t0 = 80.0 + 10 * jax.random.uniform(KEY, (grid, grid))
+        p = jax.random.uniform(jax.random.PRNGKey(1), (grid, grid))
+        ref = np.asarray(hotspot_ref(t0, p, cfg, steps))
         return cfg, np.asarray(t0), np.asarray(p), ref
 
     @pytest.mark.parametrize("start,stop", [(0, 16), (1, 17), (40, 56),
@@ -87,11 +90,62 @@ class TestHotspotBands:
         np.testing.assert_allclose(np.asarray(out)[start - lo:stop - lo],
                                    ref[start:stop], rtol=1e-5, atol=1e-4)
 
-    @pytest.mark.parametrize("start,stop", [(0, 5), (30, 61), (95, 96)])
-    def test_host_rows_match_the_oracle(self, start, stop):
-        cfg, t0, p, ref = self._problem()
-        out = hotspot_rows_host(t0, p, start, stop, cfg, self.STEPS)
+    @pytest.mark.parametrize("start,stop,grid,steps", [
+        pytest.param(0, 5, GRID, STEPS, id="0-5"),
+        pytest.param(30, 61, GRID, STEPS, id="30-61"),
+        pytest.param(95, 96, GRID, STEPS, id="95-96"),
+        # the halo reaches row 0 in the first step only
+        pytest.param(2, 18, GRID, STEPS, id="top-edge"),
+        pytest.param(78, 94, GRID, STEPS, id="bottom-edge"),
+        pytest.param(50, 51, GRID, STEPS, id="single-interior-row"),
+        pytest.param(0, GRID, GRID, STEPS, id="whole-grid"),
+        # 8 halo rows each side of a 16-row band overrun a 24-row grid
+        pytest.param(4, 20, 24, 8, id="halo-past-both-edges"),
+    ])
+    def test_host_rows_match_the_oracle(self, start, stop, grid, steps):
+        cfg, t0, p, ref = self._problem(grid, steps)
+        out = hotspot_rows_host(t0, p, start, stop, cfg, steps)
+        assert out.shape == (stop - start, grid) and out.dtype == np.float32
         np.testing.assert_allclose(out, ref[start:stop], rtol=1e-5, atol=1e-4)
+
+    def test_host_rows_on_four_threads_match_serial_bitwise(self):
+        # the CC units' contract: four threads at once on views of one
+        # grid, which the op only reads and whose scratch is its own
+        grid, steps = 512, 8
+        cfg = HotspotConfig(grid=grid, iterations=grid)
+        rng = np.random.default_rng(0)
+        t0 = (80.0 + 10.0 * rng.random((grid, grid))).astype(np.float32)
+        p = rng.random((grid, grid), dtype=np.float32)
+        t0.setflags(write=False)
+        p.setflags(write=False)
+        # bands of one height, so a scratch buffer kept between calls
+        # would be one shape, and shared
+        bands = [(16, 32), (160, 176), (300, 316), (480, 496)]
+        serial = [hotspot_rows_host(t0, p, a, b, cfg, steps) for a, b in bands]
+        gate = threading.Barrier(len(bands))
+        results = {}
+
+        def work(i, a, b):
+            gate.wait(timeout=30)
+            results[i] = [hotspot_rows_host(t0, p, a, b, cfg, steps)
+                          for _ in range(20)]
+
+        interval = sys.getswitchinterval()
+        sys.setswitchinterval(1e-5)
+        try:
+            threads = [threading.Thread(target=work, args=(i, a, b))
+                       for i, (a, b) in enumerate(bands)]
+            for th in threads:
+                th.start()
+            for th in threads:
+                th.join(timeout=60)
+        finally:
+            sys.setswitchinterval(interval)
+        assert not any(th.is_alive() for th in threads)
+        assert sorted(results) == list(range(len(bands)))
+        for i, outs in results.items():
+            for out in outs:
+                np.testing.assert_array_equal(out, serial[i])
 
     def test_band_window_slides_instead_of_shrinking(self):
         assert band_window(0, 16, 96, 22, 3) == 0
