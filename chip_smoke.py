@@ -244,7 +244,7 @@ def spmm_problem(cfg: SpmmConfig) -> Dict:
                      nnz_mean=cfg.nnz_per_row_mean,
                      nnz_sigma=cfg.nnz_per_row_sigma, seed=cfg.seed)
     sp, _ = sorted_by_density(p)
-    prob = {"cfg": cfg, "sp": sp, "be": to_block_ell(sp), "csr": HostCsr(sp),
+    prob = {"cfg": cfg, "sp": sp, "be": to_block_ell(sp), "csr": HostCsr.from_ell(sp),
             "rhs_pad": pad_rhs(sp)}
     say(f"spmm: {cfg.rows}x{cfg.cols} x {cfg.dense_cols} dense cols, "
         f"{int(sp.nnz.sum())} nonzeros (row nnz max {int(sp.nnz.max())}, "

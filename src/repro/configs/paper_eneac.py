@@ -4,6 +4,12 @@ HOTSPOT: Rodinia thermal stencil, 2048×2048 chip grid, iteration space =
 2048 rows.  SPMM: 29957×29957 sparse × 29957×100 dense, iteration space =
 29957 rows.  Table-1 sweeps FPGA chunk sizes; the throughput cliff sits at
 chunk > 1/4 of the space (512 rows HOTSPOT, 8192 rows SPMM).
+
+The paper's matrix is not in this repository, and neither is a
+description of its rows: SPMM's lognormal row lengths (mean 120, σ 1)
+and uniform columns are assumed, not the paper's.  The benchmark's SpMM
+configuration takes its pattern from a published generator instead (the
+Graph 500 Kronecker graph, ``bench/configs/spmm-graph500-s20.json``).
 """
 
 from dataclasses import dataclass
@@ -35,7 +41,7 @@ class SpmmConfig:
     rows: int = 29957
     cols: int = 29957
     dense_cols: int = 100
-    nnz_per_row_mean: float = 120.0   # irregular: lognormal row lengths
+    nnz_per_row_mean: float = 120.0   # assumed: lognormal row lengths
     nnz_per_row_sigma: float = 1.0
     seed: int = 1234
     chunk_sweep: Tuple[int, ...] = (512, 1024, 2048, 4096, 8192, 16384)

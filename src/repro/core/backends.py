@@ -60,7 +60,7 @@ from typing import Any, Callable, Dict, List, Mapping, Optional, Sequence, Tuple
 
 from .elastic import ElasticEvent
 from .scheduler import Chunk
-from .trace import ChunkTimes, Phases, Timeline, span
+from .trace import ChunkTimes, Phases, Timeline, close_work, open_work, span
 
 __all__ = [
     "BackendUnit",
@@ -135,6 +135,7 @@ class CompletionRecord:
                                  # enqueue call itself on a JaxDeviceUnit
     error: Optional[BaseException] = None
     result: Any = None           # work_fn return value (serving uses this)
+    work: Optional[int] = None   # what the chunk's ops counted (trace.add_work)
     # perf_counter_ns stamps for RunReport.timeline (ChunkTimes), from the
     # readings above: set by the local backends, 0 on transport units;
     # posted_ns by _post
@@ -287,16 +288,19 @@ class BackendUnit:
         result, error = None, None
         # the stamps sit inside the span, so elapsed times the work alone
         with span("eneac.unit_exec", unit=self.name, start=chunk.start):
+            open_work()
             t_start = time.perf_counter()
             try:
                 result = work_fn(chunk)
             except BaseException as exc:
                 error = exc
             t_end = time.perf_counter()
+            work = close_work()
         self._post(CompletionRecord(
             unit=self.name, chunk=chunk, elapsed=t_end - t_start,
             dispatch_latency=t_start - submitted, error=error, result=result,
-            submitted_ns=_ns(submitted), enqueued_ns=_ns(t_start), ready_ns=_ns(t_end),
+            work=work, submitted_ns=_ns(submitted), enqueued_ns=_ns(t_start),
+            ready_ns=_ns(t_end),
         ))
 
     def describe(self) -> str:
@@ -379,10 +383,14 @@ def _cpu_only_child() -> None:
 def _process_entry(work_fn: WorkFn, chunk: Chunk, submitted: float):
     """Runs in the pool worker; perf_counter is CLOCK_MONOTONIC, which is
     system-wide on Linux, so the dispatch latency spans the process hop."""
+    open_work()
     t_start = time.perf_counter()
-    result = work_fn(chunk)
-    t_end = time.perf_counter()
-    return result, t_end - t_start, t_start - submitted
+    try:
+        result = work_fn(chunk)
+        t_end = time.perf_counter()
+    finally:
+        work = close_work()
+    return result, t_end - t_start, t_start - submitted, work
 
 
 class ProcessPoolUnit(BackendUnit):
@@ -438,16 +446,16 @@ class ProcessPoolUnit(BackendUnit):
         fut = self._pool.submit(_process_entry, work_fn, chunk, submitted)
 
         def on_done(f, *, chunk=chunk) -> None:
-            error, result, elapsed, lat = None, None, 0.0, 0.0
+            error, result, elapsed, lat, work = None, None, 0.0, 0.0, None
             try:
-                result, elapsed, lat = f.result()
+                result, elapsed, lat, work = f.result()
             except BaseException as exc:
                 error = exc
                 elapsed = time.perf_counter() - submitted
             started = submitted + lat    # in the worker, on the same clock
             self._post(CompletionRecord(
                 unit=self.name, chunk=chunk, elapsed=elapsed,
-                dispatch_latency=lat, error=error, result=result,
+                dispatch_latency=lat, error=error, result=result, work=work,
                 submitted_ns=_ns(submitted), enqueued_ns=_ns(started),
                 ready_ns=_ns(started + elapsed),
             ))
@@ -526,7 +534,7 @@ class JaxDeviceUnit(BackendUnit):
             item = self._waitq.get()
             if item is None:
                 return
-            submitted, dispatched, chunk, out, error = item
+            submitted, dispatched, chunk, out, error, work = item
             with span("eneac.acc_wait", unit=self.name, start=chunk.start):
                 if error is None:
                     try:
@@ -537,19 +545,21 @@ class JaxDeviceUnit(BackendUnit):
             self._post(CompletionRecord(
                 unit=self.name, chunk=chunk, elapsed=t_end - dispatched,
                 dispatch_latency=dispatched - submitted, error=error,
-                result=out, submitted_ns=_ns(submitted),
+                result=out, work=work, submitted_ns=_ns(submitted),
                 enqueued_ns=_ns(dispatched), ready_ns=_ns(t_end),
             ))
 
     def submit(self, chunk: Chunk, work_fn: WorkFn) -> None:
         submitted = time.perf_counter()
         out, error = None, None
+        open_work()
         try:
             with self._jax.default_device(self._device):
                 out = work_fn(chunk)  # jitted work: enqueued, not awaited
         except BaseException as exc:
             error = exc
-        self._waitq.put((submitted, time.perf_counter(), chunk, out, error))
+        dispatched = time.perf_counter()
+        self._waitq.put((submitted, dispatched, chunk, out, error, close_work()))
 
     def close(self) -> None:
         if self._waiter is not None and self._waiter.is_alive():
@@ -723,6 +733,7 @@ class BackendEngine:
         self._chunk_times: List[ChunkTimes] = []
         self._wakeups = 0
         self._drained = 0
+        self._work: Dict[str, int] = {}       # unit -> counted work
 
     # -- helpers ------------------------------------------------------------
     def _now(self) -> float:
@@ -872,6 +883,8 @@ class BackendEngine:
             else:
                 self._inflight.pop(rec.unit, None)
             self.sched.complete(rec.unit, rec.elapsed, chunk=rec.chunk)
+            if rec.work is not None:
+                self._work[rec.unit] = self._work.get(rec.unit, 0) + rec.work
             if rec.submitted_ns:
                 self._chunk_times.append(ChunkTimes(
                     rec.unit, rec.chunk.start, rec.chunk.stop, rec.submitted_ns,
@@ -979,6 +992,13 @@ class BackendEngine:
             phase_s=self.phases.seconds, chunks=self._chunk_times,
             wakeups=self._wakeups, drained=self._drained,
         )
+
+    def per_worker_work(self) -> Optional[Dict[str, int]]:
+        """Work counted per unit (:func:`~repro.core.trace.add_work`), 0
+        for a unit whose ops counted none; ``None`` when no op counted."""
+        if not self._work:
+            return None
+        return {name: self._work.get(name, 0) for name in self.sched.workers}
 
     def dispatch_latency(self) -> Dict[str, float]:
         """Mean dispatch latency per unit, in seconds (see

@@ -116,6 +116,11 @@ class RunReport:
     # completion bus's wake-ups (repro.core.trace.Timeline): wall-clock
     # "interrupt" runs over a flat space only; None otherwise.
     timeline: Optional[Timeline] = None
+    # Work each unit's chunks counted in the op's own unit (stored entries
+    # of a sparse product), summed from repro.core.trace.add_work: 0 for a
+    # unit whose ops counted none; None when no op of the run counted
+    # (wall-clock "interrupt" runs; None otherwise).
+    per_worker_work: Optional[Dict[str, int]] = None
 
     @property
     def throughput(self) -> float:

@@ -418,6 +418,7 @@ def _build_report(
     dispatch: Optional[Dict[str, float]] = None,
     wire: Optional[Dict[str, float]] = None,
     batch_frames: Optional[Dict[str, int]] = None,
+    work: Optional[Dict[str, int]] = None,
 ) -> RunReport:
     states = sched.workers
     return RunReport(
@@ -432,6 +433,7 @@ def _build_report(
         dispatch_latency=dispatch,
         wire_latency=wire,
         batch_frames=batch_frames,
+        per_worker_work=work,
     )
 
 
@@ -889,7 +891,8 @@ class HeteroRuntime:
             with phases("report"):
                 rep = _build_report(sched, wall, dispatch=eng.dispatch_latency(),
                                     wire=eng.wire_latency(),
-                                    batch_frames=eng.frame_batching())
+                                    batch_frames=eng.frame_batching(),
+                                    work=eng.per_worker_work())
                 if eng.events:
                     rep.events = eng.events
             rep.timeline = eng.timeline()
@@ -1273,6 +1276,7 @@ def _merge_shard_reports(reports: List[RunReport]) -> RunReport:
     per_dispatch: Dict[str, float] = {}
     per_wire: Dict[str, float] = {}
     per_batch: Dict[str, int] = {}
+    per_work: Dict[str, int] = {}
     coverage: List[tuple] = []
     events: List[dict] = []
     for k, rep in enumerate(reports):
@@ -1288,6 +1292,8 @@ def _merge_shard_reports(reports: List[RunReport]) -> RunReport:
             per_wire[f"s{k}/{n}"] = v
         for n, v in (rep.batch_frames or {}).items():
             per_batch[f"s{k}/{n}"] = v
+        for n, v in (rep.per_worker_work or {}).items():
+            per_work[f"s{k}/{n}"] = v
         coverage.extend(rep.coverage or [])
         for ev in rep.events or []:
             events.append({**ev, "unit": f"s{k}/{ev['unit']}", "shard": k})
@@ -1307,4 +1313,5 @@ def _merge_shard_reports(reports: List[RunReport]) -> RunReport:
         dispatch_latency=per_dispatch or None,
         wire_latency=per_wire or None,
         batch_frames=per_batch or None,
+        per_worker_work=per_work or None,
     )

@@ -11,16 +11,22 @@ The same call sites fill :class:`Timeline`, which the ``"interrupt"``
 engine attaches to ``RunReport.timeline``: the dispatcher's seconds per
 phase (:class:`Phases`), one :class:`ChunkTimes` per completed chunk, and
 the completion bus's wake-ups.
+
+:func:`add_work` is the per-chunk work counter: an op adds the work it did
+(in its own unit: stored entries for a sparse product) to the chunk that
+the calling thread is running, and the backends sum it per unit into
+``RunReport.per_worker_work``.  Outside a chunk it does nothing.
 """
 
 from __future__ import annotations
 
 import sys
+import threading
 import time
 from dataclasses import dataclass
 from typing import Dict, List, NamedTuple
 
-__all__ = ["span", "Phases", "ChunkTimes", "Timeline"]
+__all__ = ["span", "add_work", "Phases", "ChunkTimes", "Timeline"]
 
 
 class _NoSpan:
@@ -50,6 +56,39 @@ def span(name: str, **ids):
             return _NO_SPAN
         _annotation = profiler.TraceAnnotation
     return _annotation(name, **ids)
+
+
+class _ChunkWork(threading.local):
+    """The work counted for the chunk the calling thread runs: ``open``
+    while a backend runs a chunk on this thread, ``work`` None until an op
+    counts."""
+
+    open = False
+    work = None
+
+
+_chunk_work = _ChunkWork()
+
+
+def add_work(n: int) -> None:
+    """Add ``n`` to the work of the chunk the calling thread is running;
+    a no-op outside a chunk."""
+    cw = _chunk_work
+    if cw.open:
+        cw.work = n if cw.work is None else cw.work + n
+
+
+def open_work() -> None:
+    """A backend starts a chunk on this thread: count from nothing."""
+    cw = _chunk_work
+    cw.open, cw.work = True, None
+
+
+def close_work():
+    """The chunk's counted work (None if no op counted); stops counting."""
+    cw = _chunk_work
+    work, cw.open, cw.work = cw.work, False, None
+    return work
 
 
 class Phases:

@@ -5,7 +5,10 @@ Paths (Table-1 columns):
   :func:`spmm_rows_host`, the same gather over CSR arrays in numpy for
   host-core units.
 * ``acc`` — block-ELL Pallas MXU kernel (RHS VMEM-resident), run on a
-  fixed-size window of row blocks placed by :func:`spmm_window_start`.
+  fixed-size window of row blocks placed by :func:`spmm_window_start`;
+  and, for matrices whose dense operand outgrows VMEM, the CSR window
+  :func:`~repro.kernels.spmm.csr.spmm_csr_window` (operand left in HBM),
+  placed and called through :class:`CsrWindowOp`.
 * hybrid — MultiDynamic split: densest row-prefix on the ACC path, sparse
   tail on the CC path (rows pre-sorted by density; the split point is the
   scheduler's decision, see :class:`repro.core.parallel_for.HybridExecutor`).
@@ -21,12 +24,14 @@ import jax.numpy as jnp
 import numpy as np
 
 from ...core.parallel_for import HybridExecutor, SplitDecision
+from ...core.trace import add_work, span
+from .csr import csr_window_start, csr_window_tiles, spmm_csr_window
 from .ref import COL_BLOCK, ROW_BLOCK, SpmmProblem, spmm_ell_ref, to_block_ell
 from .spmm import BlockEllArrays, spmm_block_ell_pallas
 
 __all__ = ["spmm_cc", "density_order", "sorted_by_density",
            "make_hybrid_executor", "spmm_window_start", "HostCsr",
-           "spmm_rows_host"]
+           "spmm_rows_host", "CsrWindowOp"]
 
 
 @jax.jit
@@ -62,24 +67,77 @@ def spmm_window_start(start: int, stop: int, n_row_blocks: int, window: int) -> 
 
 
 class HostCsr:
-    """CSR arrays of an ELL problem, for the numpy gather path."""
+    """CSR arrays on the host, for the numpy gather path: ``indptr``
+    (R + 1,) int64, ``indices`` (nnz,) int32, ``data`` (nnz,) f32."""
 
-    def __init__(self, p: SpmmProblem) -> None:
+    def __init__(self, indptr: np.ndarray, indices: np.ndarray, data: np.ndarray) -> None:
+        self.indptr = np.asarray(indptr, np.int64)
+        self.indices = np.asarray(indices, np.int32)
+        self.data = np.asarray(data, np.float32)
+
+    @classmethod
+    def from_ell(cls, p: SpmmProblem) -> "HostCsr":
         live = np.arange(p.vals.shape[1])[None, :] < p.nnz[:, None]
-        self.indptr = np.concatenate([[0], np.cumsum(p.nnz, dtype=np.int64)])
-        self.indices = p.cols[live]
-        self.data = p.vals[live]
+        return cls(np.concatenate([[0], np.cumsum(p.nnz, dtype=np.int64)]),
+                   p.cols[live], p.vals[live])
+
+    @property
+    def rows(self) -> int:
+        return len(self.indptr) - 1
 
 
 def spmm_rows_host(csr: HostCsr, rhs: np.ndarray, start: int, stop: int) -> np.ndarray:
-    """Rows [start, stop) of the product: Σ_j vals[r, j]·rhs[cols[r, j]]."""
-    a, b = csr.indptr[start], csr.indptr[stop]
-    prod = csr.data[a:b, None] * rhs[csr.indices[a:b]]
-    out = np.zeros((stop - start, rhs.shape[1]), np.float32)
-    nz = csr.indptr[start + 1:stop + 1] > csr.indptr[start:stop]
-    if a < b:
-        out[nz] = np.add.reduceat(prod, csr.indptr[start:stop][nz] - a, axis=0)
+    """Rows [start, stop) of the product: Σ_j vals[r, j]·rhs[cols[r, j]].
+
+    Counts the rows' stored entries as the chunk's work."""
+    a, b = int(csr.indptr[start]), int(csr.indptr[stop])
+    with span("spmm.cc_rows", nnz=b - a):
+        prod = csr.data[a:b, None] * rhs[csr.indices[a:b]]
+        out = np.zeros((stop - start, rhs.shape[1]), np.float32)
+        nz = csr.indptr[start + 1:stop + 1] > csr.indptr[start:stop]
+        if a < b:
+            out[nz] = np.add.reduceat(prod, csr.indptr[start:stop][nz] - a, axis=0)
+    add_work(b - a)
     return out
+
+
+class CsrWindowOp:
+    """A CSR matrix and its dense operand placed on one device, with
+    ``kernel`` (:func:`~repro.kernels.spmm.csr.spmm_csr_window`) compiled
+    for windows of ``window`` rows and warmed up.
+
+    X is placed with its columns padded with zeros to a multiple of 128,
+    the lane width: a (C, 100) array would be laid out column-major on a
+    TPU and copied to row-major on every call.  ``op(start, stop)``
+    enqueues the window holding rows [start, stop) and returns (its first
+    row, its (window, N_pad) device rows, of which the first N are the
+    product), without waiting; it counts the rows' stored entries as the
+    chunk's work and opens the span ``spmm.acc_window`` (``nnz``,
+    ``tiles``) over the enqueue.
+    """
+
+    def __init__(self, csr: HostCsr, rhs: np.ndarray, device, *, window: int,
+                 kernel=spmm_csr_window) -> None:
+        self.csr = csr
+        self.window = min(window, csr.rows)
+        n = rhs.shape[1]
+        x = np.zeros((rhs.shape[0], -(-n // 128) * 128), np.float32)
+        x[:, :n] = rhs
+        self.args = jax.block_until_ready(tuple(
+            jax.device_put(a, device) for a in
+            (csr.indptr.astype(np.int32), csr.indices, csr.data, x)))
+        self._exe = kernel.lower(*self.args, np.int32(0), window=self.window).compile()
+        jax.block_until_ready(self._exe(*self.args, np.int32(0)))
+
+    def __call__(self, start: int, stop: int):
+        ip = self.csr.indptr
+        lo = csr_window_start(start, stop, self.csr.rows, self.window)
+        nnz = int(ip[stop] - ip[start])
+        tiles = csr_window_tiles(ip, lo, self.window)
+        with span("spmm.acc_window", nnz=nnz, tiles=tiles):
+            out = self._exe(*self.args, np.int32(lo))
+        add_work(nnz)
+        return lo, out
 
 
 def pad_rhs(p: SpmmProblem) -> np.ndarray:

@@ -9,6 +9,10 @@ space = matrix rows, irregular nnz/row.  TPU-native layouts:
   columns in blocks of 128; per row-block the list of occupied column
   blocks, padded to the per-matrix max (irregularity shows up as padding —
   the exact trade the paper's ACC chunking makes).
+* **CSR** (:class:`CsrProblem`, for skewed matrices whose longest row or
+  dense operand makes both of the above too large: a power-law graph's
+  hub rows, an operand larger than VMEM): ``indptr``, ``indices``,
+  ``data``; its oracle is :func:`spmm_csr_ref`.
 """
 
 from __future__ import annotations
@@ -22,7 +26,7 @@ import jax.numpy as jnp
 
 __all__ = [
     "SpmmProblem", "make_problem", "spmm_dense_ref", "spmm_ell_ref",
-    "BlockEll", "to_block_ell",
+    "BlockEll", "to_block_ell", "CsrProblem", "make_csr_problem", "spmm_csr_ref",
 ]
 
 ROW_BLOCK = 8
@@ -139,3 +143,61 @@ def to_block_ell(p: SpmmProblem, *, k_cap: int = 0) -> BlockEll:
     return BlockEll(vals=vals, colblocks=colblocks,
                     counts=np.minimum(occupied, K).astype(np.int32),
                     rows=R, n_cols=n_cb * COL_BLOCK)
+
+
+@dataclasses.dataclass(frozen=True)
+class CsrProblem:
+    """CSR sparse matrix + dense RHS."""
+
+    indptr: np.ndarray    # (R + 1,) int64 row pointers
+    indices: np.ndarray   # (nnz,) int32 columns, row by row
+    data: np.ndarray      # (nnz,) f32 values
+    rhs: np.ndarray       # (C, N) f32
+
+    @property
+    def rows(self) -> int:
+        return len(self.indptr) - 1
+
+    @property
+    def n_cols(self) -> int:
+        return self.rhs.shape[0]
+
+    @property
+    def nnz(self) -> int:
+        return int(self.indptr[-1])
+
+
+def make_csr_problem(scale: int, n_dense: int, *, edgefactor: int = 16,
+                     seed: int = 0) -> CsrProblem:
+    """A power-law CSR matrix of 2**scale rows: the symmetrised adjacency
+    of a Kronecker graph with the Graph 500 initiator (0.57, 0.19, 0.19,
+    0.05), permuted labels, values uniform in [0, 1), duplicates summed and
+    self-loops dropped; RHS standard normal."""
+    rng = np.random.default_rng(seed)
+    n, m = 1 << scale, edgefactor << scale
+    quad = rng.choice(4, size=(scale, m), p=[0.57, 0.19, 0.19, 0.05])
+    weights = (1 << np.arange(scale))[:, None]
+    perm = rng.permutation(n)
+    src = perm[((quad >> 1) * weights).sum(0)]
+    dst = perm[((quad & 1) * weights).sum(0)]
+    keep = src != dst
+    w = rng.random(m)[keep]
+    src, dst = src[keep], dst[keep]
+    dense = np.zeros((n, n))
+    np.add.at(dense, (src, dst), w)
+    dense += dense.T
+    rows, cols = np.nonzero(dense)
+    indptr = np.zeros(n + 1, np.int64)
+    np.cumsum(np.bincount(rows, minlength=n), out=indptr[1:])
+    return CsrProblem(indptr=indptr, indices=cols.astype(np.int32),
+                      data=dense[rows, cols].astype(np.float32),
+                      rhs=rng.standard_normal((n, n_dense)).astype(np.float32))
+
+
+def spmm_csr_ref(p: CsrProblem) -> jax.Array:
+    """The product in float32 with plain ``jax.numpy``: densify, then one
+    matmul at the highest precision (small problems only)."""
+    rows = np.repeat(np.arange(p.rows), np.diff(p.indptr))
+    dense = jnp.zeros((p.rows, p.n_cols), jnp.float32).at[rows, p.indices].add(p.data)
+    with jax.default_matmul_precision("highest"):
+        return dense @ jnp.asarray(p.rhs, jnp.float32)

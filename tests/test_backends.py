@@ -151,6 +151,11 @@ class TestUnits:
         assert recs[0].result == sum(range(10))
         assert recs[0].error is None
 
+    def test_process_unit_counts_work_in_worker(self):
+        unit = ProcessPoolUnit("p0")
+        recs = self._drive(unit, [Chunk(3, 10, "p0")], _count_indices)
+        assert recs[0].error is None and recs[0].work == 7
+
     def test_jax_unit_dispatches_jitted_work(self):
         jax = pytest.importorskip("jax")
         import jax.numpy as jnp
@@ -212,6 +217,13 @@ class TestUnits:
         rep = rt.parallel_for(num_items=50, engine="interrupt", acc_chunk=8)
         assert rep.items == 50
         rec.assert_exactly_once(50)
+
+
+def _count_indices(chunk):
+    """Picklable work that counts its chunk's indices (``trace.add_work``)."""
+    from repro.core.trace import add_work
+
+    add_work(chunk.size)
 
 
 def _sum_indices(chunk):

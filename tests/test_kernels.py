@@ -25,7 +25,13 @@ from repro.kernels.platform import (
     resolve_interpret,
     vmem_limit,
 )
+from repro.kernels.spmm.csr import (
+    csr_window_start,
+    csr_window_tiles,
+    spmm_csr_window,
+)
 from repro.kernels.spmm.ops import (
+    CsrWindowOp,
     HostCsr,
     make_hybrid_executor,
     pad_rhs,
@@ -34,8 +40,11 @@ from repro.kernels.spmm.ops import (
     spmm_window_start,
 )
 from repro.kernels.spmm.ref import (
+    CsrProblem,
+    make_csr_problem,
     make_problem,
     spmm_dense_ref,
+    spmm_csr_ref,
     spmm_ell_ref,
     to_block_ell,
 )
@@ -207,7 +216,7 @@ class TestSpmm:
     def test_host_csr_path_matches_dense_oracle(self):
         p = make_problem(50, 300, 6, nnz_mean=5.0, seed=11)
         ref = spmm_dense_ref(p)
-        csr = HostCsr(p)
+        csr = HostCsr.from_ell(p)
         for start, stop in [(0, 50), (7, 8), (20, 44)]:
             np.testing.assert_allclose(
                 spmm_rows_host(csr, p.rhs, start, stop), ref[start:stop],
@@ -231,6 +240,84 @@ class TestSpmm:
                                           predicted_time=0.0))
             np.testing.assert_allclose(np.asarray(res)[inv], ref,
                                        rtol=1e-4, atol=1e-4)
+
+
+def _without_rows(p: CsrProblem, a: int, b: int) -> CsrProblem:
+    """``p`` with the entries of rows [a, b) dropped."""
+    ia, ib = p.indptr[a], p.indptr[b]
+    indptr = p.indptr.copy()
+    indptr[a:b + 1] = ia
+    indptr[b + 1:] -= ib - ia
+    keep = np.r_[0:ia, ib:p.nnz]
+    return CsrProblem(indptr, p.indices[keep], p.data[keep], p.rhs)
+
+
+# a SCALE 8 graph (256 rows, longest row 151) with rows 128..191 emptied
+CSR = _without_rows(make_csr_problem(8, 12, seed=5), 128, 192)
+CSR_REF = np.asarray(spmm_csr_ref(CSR))
+LONGEST = int(np.argmax(np.diff(CSR.indptr)))
+
+
+class TestSpmmCsr:
+    @pytest.mark.parametrize("start,stop,window,tile,block", [
+        (0, 64, 64, 64, 16),                          # window at the start
+        (96, 160, 64, 64, 16),                        # middle, half empty
+        (240, 256, 64, 64, 16),                       # slid back at the end
+        (LONGEST, LONGEST + 8, 32, 32, 8),            # a row longer than a tile
+        (128, 192, 64, 64, 16),                       # only empty rows
+        (30, 80, 50, 24, 16),                         # W not a multiple of tile or block
+        (0, 256, 256, 4096, 128),                     # one tile over all
+    ], ids=["start", "middle", "end", "long-row", "empty", "ragged", "whole"])
+    def test_window_matches_reference(self, start, stop, window, tile, block):
+        assert np.diff(CSR.indptr).max() > 32
+        lo = csr_window_start(start, stop, CSR.rows, window)
+        out = spmm_csr_window(jnp.asarray(CSR.indptr, jnp.int32), jnp.asarray(CSR.indices),
+                              jnp.asarray(CSR.data), jnp.asarray(CSR.rhs), np.int32(lo),
+                              window=window, tile=tile, block=block)
+        assert out.shape == (window, CSR.rhs.shape[1])
+        np.testing.assert_allclose(np.asarray(out), CSR_REF[lo:lo + window],
+                                   rtol=1e-5, atol=1e-5)
+
+    def test_window_start_slides_back_at_the_end(self):
+        assert csr_window_start(200, 230, 256, 64) == 192
+        assert csr_window_start(10, 40, 256, 64) == 10
+        with pytest.raises(ValueError, match="do not fit"):
+            csr_window_start(0, 80, 256, 64)
+
+    def test_window_tiles_follow_entries(self):
+        ip = CSR.indptr
+        assert csr_window_tiles(ip, 128, 64, tile=64) == 0                    # only empty rows
+        longest = int(ip[LONGEST + 1] - ip[LONGEST])
+        assert csr_window_tiles(ip, LONGEST, 1, tile=32) == -(-longest // 32)
+        assert csr_window_tiles(ip, 0, 64, tile=1 << 20) == 1
+        assert csr_window_tiles(ip, 0, 64, tile=8) == -(-int(ip[64] - ip[0]) // 8)
+
+    def test_op_pads_lanes_counts_entries_and_reuses_one_program(self):
+        from repro.core import trace
+
+        op = CsrWindowOp(HostCsr(CSR.indptr, CSR.indices, CSR.data), CSR.rhs,
+                         jax.devices()[0], window=64)
+        trace.open_work()
+        lo, out = op(240, 256)
+        lo2, out2 = op(0, 50)
+        assert trace.close_work() == CSR.indptr[256] - CSR.indptr[240] + CSR.indptr[50]
+        assert (lo, lo2) == (192, 0) and out.shape == (64, 128)
+        np.testing.assert_allclose(np.asarray(out)[:, :12], CSR_REF[192:256], rtol=1e-5, atol=1e-5)
+        np.testing.assert_allclose(np.asarray(out)[:, 12:], 0.0)
+        np.testing.assert_allclose(np.asarray(out2)[:, :12], CSR_REF[:64], rtol=1e-5, atol=1e-5)
+
+    def test_host_rows_on_csr_arrays(self):
+        csr = HostCsr(CSR.indptr, CSR.indices, CSR.data)
+        for start, stop in [(0, 256), (LONGEST, LONGEST + 1), (128, 192), (100, 140)]:
+            np.testing.assert_allclose(spmm_rows_host(csr, CSR.rhs, start, stop),
+                                       CSR_REF[start:stop], rtol=1e-5, atol=1e-5)
+
+    def test_reference_matches_the_dense_oracle(self):
+        p = make_problem(40, 90, 6, nnz_mean=5.0, seed=2)
+        csr = HostCsr.from_ell(p)
+        q = CsrProblem(csr.indptr, csr.indices, csr.data, p.rhs)
+        np.testing.assert_allclose(np.asarray(spmm_csr_ref(q)), spmm_dense_ref(p),
+                                   rtol=1e-5, atol=1e-5)
 
 
 class TestFlashAttention:

@@ -228,3 +228,79 @@ def test_core_does_not_import_jax():
                           text=True, timeout=120,
                           env=dict(os.environ, PYTHONPATH=str(ROOT / "src")))
     assert "NOJAX_OK" in proc.stdout, proc.stderr
+
+
+# --- the per-chunk work counter (trace.add_work, RunReport.per_worker_work) --
+
+def _spmm_runtime(acc_backend: str):
+    """2 ACC units running the CSR window op (on the device, or on a host
+    backend) and 2 CC threads running the numpy op, over a SCALE 9 graph."""
+    import numpy as np
+
+    from repro.kernels.spmm.ops import CsrWindowOp, HostCsr, spmm_rows_host
+    from repro.kernels.spmm.ref import make_csr_problem
+
+    p = make_csr_problem(9, 8, seed=1)
+    csr = HostCsr(p.indptr, p.indices, p.data)
+    op = CsrWindowOp(csr, p.rhs, jax.devices()[0], window=32)
+    rt = HeteroRuntime(clock=WallClock())
+    for i in range(2):
+        rt.register_unit(f"acc{i}", WorkerKind.ACC, backend=acc_backend,
+                         work_fn=lambda c: op(c.start, c.stop)[1])
+        rt.register_unit(f"cc{i}", WorkerKind.CC, backend="thread",
+                         work_fn=lambda c: spmm_rows_host(csr, p.rhs, c.start, c.stop))
+    assert p.nnz == int(np.diff(p.indptr).sum()) > 0
+    return rt, p
+
+
+@pytest.mark.parametrize("acc_backend", ["jax", "thread", "inline"])
+def test_work_totals_are_the_matrix_entries(acc_backend):
+    rt, p = _spmm_runtime(acc_backend)
+    trace_mod.add_work(1000)           # outside a chunk: counts nowhere
+    rep = rt.parallel_for(num_items=p.rows, acc_chunk=32)
+    work = rep.per_worker_work
+    assert set(work) == set(rep.per_worker_items)
+    assert sum(work.values()) == p.nnz
+    entries = dict.fromkeys(work, 0)
+    for c in rep.timeline.chunks:
+        entries[c.unit] += int(p.indptr[c.stop] - p.indptr[c.start])
+    assert work == entries
+    assert work["acc0"] + work["acc1"] > 0
+
+
+def test_work_is_none_where_no_op_counts(wall_report):
+    assert wall_report.per_worker_work is None
+
+
+def test_add_work_outside_a_chunk_is_a_no_op():
+    trace_mod.add_work(5)
+    assert trace_mod.close_work() is None
+    trace_mod.open_work()
+    trace_mod.add_work(2)
+    trace_mod.add_work(3)
+    assert trace_mod.close_work() == 5
+    trace_mod.add_work(7)
+    assert trace_mod.close_work() is None
+
+
+def test_sharded_run_namespaces_the_work():
+    rt, p = _spmm_runtime("thread")
+    rep = rt.parallel_for(space=ShardedSpace(p.rows, 2), acc_chunk=32)
+    assert sum(rep.per_worker_work.values()) == p.nnz
+    assert all(name.startswith(("s0/", "s1/")) for name in rep.per_worker_work)
+
+
+def test_profiler_records_the_spmm_spans(tmp_path):
+    rt, p = _spmm_runtime("jax")
+    rt.parallel_for(num_items=p.rows, acc_chunk=32)
+    with jax.profiler.trace(str(tmp_path)):
+        rep = rt.parallel_for(num_items=p.rows, acc_chunk=32)
+    path = glob.glob(str(tmp_path / "plugins/profile/*/*.xplane.pb"))[0]
+    events = [(e.name, dict(e.stats)) for plane in ProfileData.from_file(path).planes
+              if plane.name.startswith("/host:") for line in plane.lines
+              for e in line.events if e.name.startswith("spmm.")]
+    acc = [a for n, a in events if n == "spmm.acc_window"]
+    cc = [a for n, a in events if n == "spmm.cc_rows"]
+    assert len(acc) + len(cc) == rep.chunks and acc
+    assert all(a["tiles"] >= 0 for a in acc)
+    assert sum(a["nnz"] for a in acc + cc) == p.nnz

@@ -1,10 +1,11 @@
-"""The main path's Pallas kernels compile for a TPU v5e at real sizes.
+"""The main path's kernels compile for a TPU v5e at real sizes.
 
 No chip is needed: the TPU compiler compiles for a described ``v5e:2x2``
 topology, and refuses what the chip would refuse (tiles that break the
-layout rules, more VMEM or SMEM than a kernel may use).  Every test
-asserts that the kernel lowered to ``tpu_custom_call``, i.e. that it runs
-compiled and not in the interpreter.
+layout rules, more VMEM or SMEM than a kernel may use).  Every test of a
+Pallas kernel asserts that it lowered to ``tpu_custom_call``, i.e. that it
+runs compiled and not in the interpreter; the CSR window, an XLA loop,
+asserts that it keeps its operands where they are.
 
 The topology is described inside a fixture, never at import: only one
 process may load the TPU library, so collection must not touch it.
@@ -20,6 +21,7 @@ from jax.sharding import SingleDeviceSharding
 from repro.configs.paper_eneac import HotspotConfig, SpmmConfig
 from repro.kernels.hotspot.hotspot import hotspot_hp_step_pallas, hotspot_hpc_pallas
 from repro.kernels.hotspot.ops import hotspot_hpc_window
+from repro.kernels.spmm.csr import spmm_csr_window
 from repro.kernels.spmm.ref import COL_BLOCK, ROW_BLOCK
 from repro.kernels.spmm.spmm import spmm_block_ell_window
 
@@ -100,3 +102,18 @@ def test_spmm_window_compiles_at_paper_chunk(one_chip):
         _sds(one_chip, (), jnp.int32),
         n_row_blocks=SPMM_WINDOW, interpret=False).compile()
     _assert_kernel(compiled)
+
+
+def test_spmm_csr_window_compiles_at_graph500_scale_20(one_chip):
+    # the cell spmm-graph500-s20.hybrid: 2**20 rows, 31.4M entries, X padded
+    # to 128 lanes, windows of 16384 rows; the scratch is one trip's rows and
+    # the window's output, less than the smallest operand: none is copied
+    rows, nnz = 1 << 20, 31_404_556
+    compiled = spmm_csr_window.lower(
+        _sds(one_chip, (rows + 1,), jnp.int32), _sds(one_chip, (nnz,), jnp.int32),
+        _sds(one_chip, (nnz,)), _sds(one_chip, (rows, 128)), _sds(one_chip, (), jnp.int32),
+        window=16384).compile()
+    mem = compiled.memory_analysis()
+    assert mem.output_size_in_bytes == 16384 * 128 * 4
+    assert mem.temp_size_in_bytes < 64 * 1024 * 1024 < nnz * 4
+    assert compiled.as_text().count(" while(") == 1
