@@ -136,6 +136,9 @@ class CompletionRecord:
     error: Optional[BaseException] = None
     result: Any = None           # work_fn return value (serving uses this)
     work: Optional[int] = None   # what the chunk's ops counted (trace.add_work)
+    # bytes of the result's jax.Array leaves whose host copy the unit
+    # started (JaxDeviceUnit); None when it started none
+    host_copy_bytes: Optional[int] = None
     # perf_counter_ns stamps for RunReport.timeline (ChunkTimes), from the
     # readings above: set by the local backends, 0 on transport units;
     # posted_ns by _post
@@ -484,6 +487,16 @@ class JaxDeviceUnit(BackendUnit):
     nothing to block on, so completion fires after dispatch), but then
     the elapsed time only covers the host-side call.
 
+    When the waiter takes a chunk, before it blocks, it starts the host
+    copy of every ``jax.Array`` leaf of the result
+    (``copy_to_host_async``): the runtime queues the transfer behind the
+    chunk's computation, so it runs while later chunks compute and a
+    caller's ``np.asarray`` or ``jax.device_get`` finds it done or in
+    flight.  Completion still means device-ready.  The bytes started are
+    the record's :attr:`CompletionRecord.host_copy_bytes`; results
+    without such leaves (``None``, numpy, Python scalars) and work
+    functions that raised are left alone.
+
     ``device`` is a ``jax.Device`` or an index into ``jax.devices()``
     (default 0).  An index that does not exist raises; it never wraps.
     A work function whose arrays are committed to another device runs
@@ -535,7 +548,14 @@ class JaxDeviceUnit(BackendUnit):
             if item is None:
                 return
             submitted, dispatched, chunk, out, error, work = item
-            with span("eneac.acc_wait", unit=self.name, start=chunk.start):
+            copied = None
+            if error is None:
+                try:
+                    copied = self._start_host_copy(out)   # outside the span
+                except BaseException as exc:
+                    error = exc
+            with span("eneac.acc_wait", unit=self.name, start=chunk.start,
+                      host_copy_bytes=copied or 0):
                 if error is None:
                     try:
                         self._jax.block_until_ready(out)
@@ -545,9 +565,20 @@ class JaxDeviceUnit(BackendUnit):
             self._post(CompletionRecord(
                 unit=self.name, chunk=chunk, elapsed=t_end - dispatched,
                 dispatch_latency=dispatched - submitted, error=error,
-                result=out, work=work, submitted_ns=_ns(submitted),
-                enqueued_ns=_ns(dispatched), ready_ns=_ns(t_end),
+                result=out, work=work, host_copy_bytes=copied,
+                submitted_ns=_ns(submitted), enqueued_ns=_ns(dispatched),
+                ready_ns=_ns(t_end),
             ))
+
+    def _start_host_copy(self, out) -> Optional[int]:
+        """Start the host copy of each ``jax.Array`` leaf of ``out``; the
+        bytes started, or None when ``out`` has no such leaf."""
+        started = None
+        for leaf in self._jax.tree_util.tree_leaves(out):
+            if isinstance(leaf, self._jax.Array):
+                leaf.copy_to_host_async()
+                started = (started or 0) + leaf.nbytes
+        return started
 
     def submit(self, chunk: Chunk, work_fn: WorkFn) -> None:
         submitted = time.perf_counter()
@@ -734,6 +765,7 @@ class BackendEngine:
         self._wakeups = 0
         self._drained = 0
         self._work: Dict[str, int] = {}       # unit -> counted work
+        self._host_copy: Dict[str, int] = {}  # unit -> host copy bytes started
 
     # -- helpers ------------------------------------------------------------
     def _now(self) -> float:
@@ -885,6 +917,9 @@ class BackendEngine:
             self.sched.complete(rec.unit, rec.elapsed, chunk=rec.chunk)
             if rec.work is not None:
                 self._work[rec.unit] = self._work.get(rec.unit, 0) + rec.work
+            if rec.host_copy_bytes is not None:
+                self._host_copy[rec.unit] = (self._host_copy.get(rec.unit, 0)
+                                             + rec.host_copy_bytes)
             if rec.submitted_ns:
                 self._chunk_times.append(ChunkTimes(
                     rec.unit, rec.chunk.start, rec.chunk.stop, rec.submitted_ns,
@@ -999,6 +1034,14 @@ class BackendEngine:
         if not self._work:
             return None
         return {name: self._work.get(name, 0) for name in self.sched.workers}
+
+    def per_worker_host_copy_bytes(self) -> Optional[Dict[str, int]]:
+        """Bytes whose host copy each unit started
+        (:attr:`CompletionRecord.host_copy_bytes`), 0 for a unit that
+        started none; ``None`` when no unit started one."""
+        if not self._host_copy:
+            return None
+        return {name: self._host_copy.get(name, 0) for name in self.sched.workers}
 
     def dispatch_latency(self) -> Dict[str, float]:
         """Mean dispatch latency per unit, in seconds (see

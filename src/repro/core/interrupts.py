@@ -121,6 +121,12 @@ class RunReport:
     # unit whose ops counted none; None when no op of the run counted
     # (wall-clock "interrupt" runs; None otherwise).
     per_worker_work: Optional[Dict[str, int]] = None
+    # Bytes of ACC chunk results whose device-to-host copy each unit
+    # started when its waiter took the chunk (JaxDeviceUnit: the nbytes of
+    # the result's jax.Array leaves): 0 for a unit that started none; None
+    # when no unit started one (wall-clock "interrupt" runs; None
+    # otherwise).
+    per_worker_host_copy_bytes: Optional[Dict[str, int]] = None
 
     @property
     def throughput(self) -> float:

@@ -419,6 +419,7 @@ def _build_report(
     wire: Optional[Dict[str, float]] = None,
     batch_frames: Optional[Dict[str, int]] = None,
     work: Optional[Dict[str, int]] = None,
+    host_copy: Optional[Dict[str, int]] = None,
 ) -> RunReport:
     states = sched.workers
     return RunReport(
@@ -434,6 +435,7 @@ def _build_report(
         wire_latency=wire,
         batch_frames=batch_frames,
         per_worker_work=work,
+        per_worker_host_copy_bytes=host_copy,
     )
 
 
@@ -892,7 +894,8 @@ class HeteroRuntime:
                 rep = _build_report(sched, wall, dispatch=eng.dispatch_latency(),
                                     wire=eng.wire_latency(),
                                     batch_frames=eng.frame_batching(),
-                                    work=eng.per_worker_work())
+                                    work=eng.per_worker_work(),
+                                    host_copy=eng.per_worker_host_copy_bytes())
                 if eng.events:
                     rep.events = eng.events
             rep.timeline = eng.timeline()
@@ -1277,6 +1280,7 @@ def _merge_shard_reports(reports: List[RunReport]) -> RunReport:
     per_wire: Dict[str, float] = {}
     per_batch: Dict[str, int] = {}
     per_work: Dict[str, int] = {}
+    per_copy: Dict[str, int] = {}
     coverage: List[tuple] = []
     events: List[dict] = []
     for k, rep in enumerate(reports):
@@ -1294,6 +1298,8 @@ def _merge_shard_reports(reports: List[RunReport]) -> RunReport:
             per_batch[f"s{k}/{n}"] = v
         for n, v in (rep.per_worker_work or {}).items():
             per_work[f"s{k}/{n}"] = v
+        for n, v in (rep.per_worker_host_copy_bytes or {}).items():
+            per_copy[f"s{k}/{n}"] = v
         coverage.extend(rep.coverage or [])
         for ev in rep.events or []:
             events.append({**ev, "unit": f"s{k}/{ev['unit']}", "shard": k})
@@ -1314,4 +1320,5 @@ def _merge_shard_reports(reports: List[RunReport]) -> RunReport:
         wire_latency=per_wire or None,
         batch_frames=per_batch or None,
         per_worker_work=per_work or None,
+        per_worker_host_copy_bytes=per_copy or None,
     )

@@ -303,6 +303,90 @@ class TestJaxDeviceIndex:
 
 
 # ---------------------------------------------------------------------------
+# JaxDeviceUnit starts the host copy of a chunk's jax.Array results when its
+# waiter takes the chunk; the record counts the bytes it started
+# ---------------------------------------------------------------------------
+def _jax_results():
+    """Work functions by result kind, and the bytes whose copy they start."""
+    import jax.numpy as jnp
+    import numpy as np
+
+    a = jnp.arange(6, dtype=jnp.float32)
+    return {
+        "none": (lambda c: None, None),
+        "numpy": (lambda c: np.ones(5, np.float32), None),
+        "int-and-array": (lambda c: (c.start, a * 2), a.nbytes),
+        "array": (lambda c: a + c.start, a.nbytes),
+        "nested": (lambda c: {"x": a, "y": [a[:2], 3.0]}, a.nbytes + 8),
+    }
+
+
+class TestJaxHostCopy:
+    def _record(self, work_fn):
+        bus = CompletionBus()
+        unit = JaxDeviceUnit("d0")
+        unit.start(bus)
+        try:
+            unit.submit(Chunk(0, 4, "d0"), work_fn)
+            assert bus.wait(timeout=10.0)
+            (rec,) = bus.drain()
+            return rec
+        finally:
+            unit.close()
+
+    @pytest.mark.parametrize("kind", sorted(_jax_results()))
+    def test_counts_only_the_array_leaves(self, kind):
+        work_fn, nbytes = _jax_results()[kind]
+        rec = self._record(work_fn)
+        assert rec.error is None
+        assert rec.host_copy_bytes == nbytes
+
+    def test_a_raising_work_function_surfaces_and_counts_nothing(self):
+        def boom(c):
+            raise RuntimeError("kaput")
+
+        rec = self._record(boom)
+        assert isinstance(rec.error, RuntimeError)
+        assert rec.host_copy_bytes is None and rec.result is None
+        rt = HeteroRuntime()
+        rt.register_unit("d0", WorkerKind.ACC, backend="jax", work_fn=boom)
+        with pytest.raises(RuntimeError, match="kaput"):
+            rt.parallel_for(num_items=16, engine="interrupt", acc_chunk=4)
+
+    def test_results_are_bitwise_those_of_a_waiter_that_copies_nothing(
+            self, monkeypatch):
+        import jax
+        import jax.numpy as jnp
+        import numpy as np
+
+        f = jax.jit(lambda x, s: jnp.sin(x * s) @ x.T)
+        x = jax.random.normal(jax.random.key(7), (64, 64), jnp.float32)
+
+        def run():
+            rt = HeteroRuntime()
+            out = {}
+
+            def work(c):
+                out[c.start] = f(x, jnp.float32(c.start + 1))
+                return out[c.start]
+            for i in range(2):
+                rt.register_unit(f"d{i}", WorkerKind.ACC, backend="jax",
+                                 work_fn=work)
+            rep = rt.parallel_for(num_items=64, engine="interrupt", acc_chunk=8)
+            return rep, {s: np.asarray(v) for s, v in out.items()}
+
+        rep, copied = run()
+        assert sum(rep.per_worker_host_copy_bytes.values()) == 8 * 64 * 64 * 4
+        monkeypatch.setattr(JaxDeviceUnit, "_start_host_copy",
+                            lambda self, out: None)
+        rep_plain, plain = run()
+        assert rep_plain.per_worker_host_copy_bytes is None
+        assert sorted(copied) == sorted(plain) == list(range(0, 64, 8))
+        for s in plain:
+            assert copied[s].tobytes() == plain[s].tobytes(), s
+
+
+# ---------------------------------------------------------------------------
 # make_backend negatives (ISSUE 5 satellite): an unknown spec must teach
 # the caller every valid spec, including the remote: form
 # ---------------------------------------------------------------------------

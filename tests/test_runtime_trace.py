@@ -304,3 +304,37 @@ def test_profiler_records_the_spmm_spans(tmp_path):
     assert len(acc) + len(cc) == rep.chunks and acc
     assert all(a["tiles"] >= 0 for a in acc)
     assert sum(a["nnz"] for a in acc + cc) == p.nnz
+
+
+
+# --- the host copy an ACC unit starts (RunReport.per_worker_host_copy_bytes) --
+
+def test_host_copy_bytes_follow_the_acc_results(wall_report, request):
+    copied = wall_report.per_worker_host_copy_bytes
+    if request.node.callspec.params["wall_report"] != "jax":
+        assert copied is None          # host units return host values
+        return
+    assert set(copied) == set(wall_report.per_worker_items)
+    for unit, n in wall_report.per_worker_chunks.items():
+        assert copied[unit] == (n * _X.nbytes if unit.startswith("acc") else 0), unit
+
+
+def test_sharded_run_sums_the_host_copy_bytes():
+    rep = _wall_runtime("jax").parallel_for(space=ShardedSpace(96, 2), acc_chunk=8)
+    merged = rep.per_worker_host_copy_bytes
+    assert all(name.startswith(("s0/", "s1/")) for name in merged)
+    for k, shard in enumerate(rep.shard_reports):
+        for unit, v in shard.per_worker_host_copy_bytes.items():
+            assert merged[f"s{k}/{unit}"] == v
+    acc_chunks = sum(n for u, n in rep.per_worker_chunks.items() if "/acc" in u)
+    assert sum(merged.values()) == acc_chunks * _X.nbytes > 0
+
+
+def test_acc_wait_span_carries_the_host_copy_bytes(tmp_path):
+    rt = _wall_runtime("jax")
+    rt.parallel_for(num_items=64, acc_chunk=8)
+    with jax.profiler.trace(str(tmp_path)):
+        rep = rt.parallel_for(num_items=64, acc_chunk=8)
+    waits = [a for n, a in _events(tmp_path) if n == "eneac.acc_wait"]
+    assert waits and all(a["host_copy_bytes"] == _X.nbytes for a in waits)
+    assert sum(rep.per_worker_host_copy_bytes.values()) == len(waits) * _X.nbytes
