@@ -13,9 +13,8 @@ Graph 500 Kronecker graph, ``bench/configs/spmm-graph500-s20.json``).
 """
 
 from dataclasses import dataclass
-from typing import Tuple
 
-__all__ = ["HotspotConfig", "SpmmConfig", "HOTSPOT", "SPMM", "TABLE1_CONFIGS"]
+__all__ = ["HotspotConfig", "SpmmConfig"]
 
 
 @dataclass(frozen=True)
@@ -32,8 +31,6 @@ class HotspotConfig:
     spec_heat_si: float = 1.75e6
     k_si: float = 100.0
     amb_temp: float = 80.0
-    # chunk sweep (paper Fig. 4a): cliff above 512 (= grid/4)
-    chunk_sweep: Tuple[int, ...] = (32, 64, 128, 256, 512, 1024)
 
 
 @dataclass(frozen=True)
@@ -44,23 +41,4 @@ class SpmmConfig:
     nnz_per_row_mean: float = 120.0   # assumed: lognormal row lengths
     nnz_per_row_sigma: float = 1.0
     seed: int = 1234
-    chunk_sweep: Tuple[int, ...] = (512, 1024, 2048, 4096, 8192, 16384)
 
-
-HOTSPOT = HotspotConfig()
-SPMM = SpmmConfig()
-
-# Table-1 platform configurations, reproduced on the TPU mapping:
-#   CC   = VPU/gather path (jnp row-wise)           [CPU cores]
-#   HP   = Pallas kernel, HBM re-fetch per step     [non-cacheable port]
-#   HPC  = Pallas kernel, VMEM-resident revisiting  [cache-coherent port]
-#   +INT = completion-driven AsyncEngine            [interrupt mechanism]
-TABLE1_CONFIGS = (
-    ("1", "4CC", "cc", None, False),
-    ("2", "4HPACC", "acc", "hp", False),
-    ("3", "4HPCACC", "acc", "hpc", False),
-    ("4", "4CC+4HPACC", "hybrid", "hp", False),
-    ("5", "4CC+4HPACC+INT", "hybrid", "hp", True),
-    ("6", "4CC+4HPCACC", "hybrid", "hpc", False),
-    ("7", "4CC+4HPCACC+INT", "hybrid", "hpc", True),
-)

@@ -31,7 +31,7 @@
 """
 
 from .scheduler import Chunk, MultiDynamicScheduler, OracleStaticScheduler, StaticScheduler, WorkerKind
-from .interrupts import AsyncEngine, CompletionEvent, PollingEngine, RunReport
+from .interrupts import CompletionEvent, PollingEngine, RunReport
 from .backends import (
     BackendEngine,
     BackendUnit,
@@ -90,7 +90,6 @@ __all__ = [
     "StaticScheduler",
     "OracleStaticScheduler",
     "WorkerKind",
-    "AsyncEngine",
     "PollingEngine",
     "CompletionEvent",
     "RunReport",

@@ -1,12 +1,11 @@
 """Backend units: genuine asynchronous dispatch for wall-clock runs.
 
-Before this module, a :class:`~repro.core.runtime.WallClock` run executed
-every ``work_fn`` *inside* the engine's own threads — asynchrony was an
-artifact of how :class:`~repro.core.interrupts.AsyncEngine` was written,
-not a property of the compute units.  The paper's model (and HEROv2's
-runtime) is the opposite: each heterogeneous processing unit is a real
-execution resource with its own stream, the host *submits* work to it and
-is told — asynchronously — when the unit finishes.  This module reifies
+Executing every ``work_fn`` *inside* an engine's own threads would make
+asynchrony an artifact of how the engine is written, not a property of
+the compute units.  The paper's model (and HEROv2's runtime) is the
+opposite: each heterogeneous processing unit is a real execution
+resource with its own stream, the host *submits* work to it and is
+told — asynchronously — when the unit finishes.  This module reifies
 that boundary:
 
 * :class:`BackendUnit` — the protocol: ``start(bus)`` /

@@ -1,42 +1,40 @@
-"""Completion-driven execution engine (ENEAC §3.2 interrupt mechanism).
+"""Completion events, the polling engine and the run report.
 
 The paper attaches a dedicated hardware interrupt controller + software
 driver + host thread to *each* FPGA accelerator, so (a) every accelerator
 runs fully asynchronously and (b) the host thread that offloaded a chunk
 sleeps until the interrupt fires instead of burning a CPU core polling.
-
-TPU/JAX adaptation: there are no user-visible interrupts, but JAX's async
-dispatch gives the same structure — device work is enqueued and the host
-is only blocked when it *chooses* to synchronize.  We reify the paper's
-design as:
+On the TPU, JAX's async dispatch gives the same structure, and the
+event-driven dispatcher that realises it is
+:class:`~repro.core.backends.BackendEngine` (``engine="interrupt"``).
+This module keeps what sits beside it:
 
 * :class:`CompletionEvent` — the interrupt analogue: ``fire()`` from the
   completion context (device callback, worker thread), ``wait()`` from the
   offloading host thread which *sleeps* on a condition variable.
-* :class:`AsyncEngine` — one host thread per compute unit (exactly the
-  paper's per-accelerator host thread), each looping: request chunk from
-  the scheduler → dispatch → sleep until completion → report → repeat.
 * :class:`PollingEngine` — the "no interrupts" baseline of Table-1 configs
   (4) and (6): a single host thread busy-spins over the units checking for
-  completion, stealing cycles from the CC workers.  For the benchmark we
-  model the steal by running CC work on the *same* thread that polls.
+  completion, stealing cycles from the CC workers.  We model the steal by
+  running CC work on the *same* thread that polls (``engine="polling"``,
+  and ``"inline"`` without the poll interval).
+* :class:`RunReport` — what every engine returns.
 
 Both engines drive the *same* :class:`~repro.core.scheduler.MultiDynamicScheduler`,
-so the Table-1 reproduction isolates the interrupt mechanism exactly as the
-paper does (config (6) vs (7), (4) vs (5)).
+so comparing them isolates the interrupt mechanism exactly as the paper
+does (config (6) vs (7), (4) vs (5)).
 """
 
 from __future__ import annotations
 
 import threading
 import time
-from dataclasses import dataclass, field
+from dataclasses import dataclass
 from typing import Callable, Dict, List, Optional
 
 from .scheduler import Chunk, MultiDynamicScheduler
 from .trace import Timeline
 
-__all__ = ["CompletionEvent", "AsyncEngine", "PollingEngine", "RunReport"]
+__all__ = ["CompletionEvent", "PollingEngine", "RunReport"]
 
 
 class CompletionEvent:
@@ -164,68 +162,6 @@ class RunReport:
 
 
 WorkFn = Callable[[Chunk], None]
-
-
-class AsyncEngine:
-    """Per-unit host threads + completion events (the paper's §3.2 design).
-
-    ``work_fns[name]`` performs one chunk on unit ``name`` and returns when
-    the unit's result is available (for JAX work this is where the function
-    calls ``block_until_ready`` on *its own* stream — other units keep
-    running, which is the entire point).
-    """
-
-    def __init__(self, scheduler: MultiDynamicScheduler, work_fns: Dict[str, WorkFn]) -> None:
-        self.scheduler = scheduler
-        self.work_fns = work_fns
-        missing = set(scheduler.workers) - set(work_fns)
-        if missing:
-            raise ValueError(f"no work_fn for workers {sorted(missing)}")
-        self._errors: List[BaseException] = []
-        self._error_lock = threading.Lock()
-
-    def _host_thread(self, name: str) -> None:
-        fn = self.work_fns[name]
-        while True:
-            chunk = self.scheduler.next_chunk(name, now=time.perf_counter())
-            if chunk is None:
-                return
-            t0 = time.perf_counter()
-            try:
-                fn(chunk)
-            except BaseException as exc:  # propagate to .run()
-                with self._error_lock:
-                    self._errors.append(exc)
-                self.scheduler.complete(name, time.perf_counter() - t0)
-                return
-            self.scheduler.complete(name, time.perf_counter() - t0)
-
-    def run(self) -> RunReport:
-        t0 = time.perf_counter()
-        threads = [
-            threading.Thread(target=self._host_thread, args=(name,), name=f"eneac-{name}")
-            for name in self.scheduler.workers
-        ]
-        for t in threads:
-            t.start()
-        for t in threads:
-            t.join()
-        if self._errors:
-            raise self._errors[0]
-        wall = time.perf_counter() - t0
-        return self._report(wall)
-
-    def _report(self, wall: float) -> RunReport:
-        states = self.scheduler.workers
-        return RunReport(
-            wall_time=wall,
-            items=sum(w.items_done for w in states.values()),
-            chunks=sum(w.chunks_done for w in states.values()),
-            per_worker_items={n: w.items_done for n, w in states.items()},
-            per_worker_chunks={n: w.chunks_done for n, w in states.items()},
-            per_worker_busy={n: w.total_busy_time for n, w in states.items()},
-            load_balance=self.scheduler.load_balance(),
-        )
 
 
 class PollingEngine:

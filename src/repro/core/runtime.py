@@ -5,10 +5,10 @@ units, hand each idle unit a chunk of the iteration space the moment it
 completes the previous one, and adapt chunk sizes from measured
 throughput.  Before this module the three pillars of that loop —
 :class:`~repro.core.scheduler.MultiDynamicScheduler` (chunking policy),
-:class:`~repro.core.interrupts.AsyncEngine` / ``PollingEngine``
-(completion mechanism), and the workload adapters
+the completion mechanism (:class:`~repro.core.backends.BackendEngine` /
+:class:`~repro.core.interrupts.PollingEngine`), and the workload adapters
 (:class:`~repro.core.parallel_for.HybridExecutor`, the serving refill
-loop, the Table-1 harness) — were wired ad hoc at every call site.
+loop) — were wired ad hoc at every call site.
 :class:`HeteroRuntime` is the one front door:
 
     rt = HeteroRuntime()
@@ -179,7 +179,7 @@ class _FixedScheduler:
 class _TrackedScheduler:
     """Engine-facing facade over any chunking policy.
 
-    The engines (:class:`AsyncEngine`, :class:`PollingEngine`) and the
+    The engines (:class:`BackendEngine`, :class:`PollingEngine`) and the
     report builder need per-unit state, coverage history, and load-balance
     metrics; only :class:`MultiDynamicScheduler` keeps those natively.
     This facade adds uniform bookkeeping on top of every policy, so one

@@ -23,9 +23,7 @@ separate schedulers that look identical under uniform load.
   requests that met their deadline) is measurable, not assumed.
 
 ``run_trace`` returns a stable metrics dict (p50/p95/p99 latency, TTFT,
-goodput, shed/failed counts) — the same schema
-``benchmarks/bench_serving.py`` commits to ``BENCH_serving.json`` so
-every PR leaves a visible perf trajectory.
+goodput, shed/failed counts): the keys of :data:`METRIC_KEYS`.
 """
 
 from __future__ import annotations
@@ -50,8 +48,8 @@ __all__ = [
 
 ARRIVALS = ("poisson", "bursty", "uniform")
 
-# The stable schema of run_trace()/summarize() — tools/check_bench.py
-# validates committed artifacts against exactly this set.
+# The stable schema of run_trace()/summarize(): every result carries
+# exactly this set of keys.
 METRIC_KEYS = (
     "requests", "completed", "failed", "shed",
     "wall_time_s", "tokens",
@@ -161,7 +159,7 @@ def make_trace(
 def _pct(xs: Sequence[float], p: float) -> float:
     # nan, not 0.0: a run that completed nothing has *no* latency
     # distribution, and a 0.0s p99 reads as an impossibly good pass.
-    # Consumers (tools/check_bench.py) treat nan as "no data".
+    # Consumers treat nan as "no data".
     return float(np.percentile(list(xs), p)) if len(xs) else float("nan")
 
 

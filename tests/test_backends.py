@@ -649,21 +649,34 @@ class TestWallEngine:
         assert all(v >= 0 for v in rep.dispatch_latency.values())
 
     def test_work_overlaps_on_real_threads(self):
-        # with per-chunk sleeps, N threads must beat the serial sum;
-        # inline execution (same engine, no overlap) is the control
-        def run(backend):
-            rec = Recorder(per_item_sleep=1e-4)
-            t0 = time.perf_counter()
-            make_wall_runtime(rec, n_units=4, backend=backend).parallel_for(
-                num_items=600, policy="static", engine="interrupt",
-            )
-            return time.perf_counter() - t0
+        # "threads": the four units' chunks (static policy: one each) meet
+        # at a barrier, which only passes if all four run at once, and the
+        # timeline's stamps share an instant.  "inline" (same engine, work
+        # on the dispatcher thread) is the control: no two chunks overlap.
+        barrier = threading.Barrier(4, timeout=30)
+        rec = Recorder()
 
-        wall_threads = run("threads")
-        wall_inline = run("inline")
-        # 4-way overlap over 15ms/unit sleeps vs a 60ms serial sweep: even
-        # with scheduler/thread overhead the ratio sits near 0.3
-        assert wall_threads < wall_inline * 0.7, (wall_threads, wall_inline)
+        def meet(chunk):
+            barrier.wait()
+            rec(chunk)
+
+        rep = make_wall_runtime(meet, n_units=4, backend="threads").parallel_for(
+            num_items=600, policy="static", engine="interrupt",
+        )
+        rec.assert_exactly_once(600)
+        spans = rep.timeline.chunks
+        assert len(spans) == 4
+        assert max(c.enqueued for c in spans) < min(c.ready for c in spans)
+
+        rec = Recorder(per_item_sleep=1e-5)
+        rep = make_wall_runtime(rec, n_units=4, backend="inline").parallel_for(
+            num_items=600, policy="static", engine="interrupt",
+        )
+        rec.assert_exactly_once(600)
+        spans = sorted(rep.timeline.chunks, key=lambda c: c.enqueued)
+        assert len(spans) == 4
+        for a, b in zip(spans, spans[1:]):
+            assert a.ready <= b.enqueued, (a, b)
 
     def test_error_in_work_fn_propagates(self):
         def boom(c):

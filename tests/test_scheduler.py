@@ -12,11 +12,11 @@ except ImportError:  # CI container has no hypothesis; use the vendored shim
     from _propcheck import given, settings, strategies as st
 
 from repro.core import (
-    AsyncEngine,
+    HeteroRuntime,
     MultiDynamicScheduler,
     OracleStaticScheduler,
-    PollingEngine,
     StaticScheduler,
+    WallClock,
     WorkerKind,
 )
 from repro.core.scheduler import (
@@ -113,32 +113,63 @@ class TestCoverage:
 
 
 class TestEngines:
-    def _run(self, engine_cls, rates, n_items=400, **kw):
-        s = MultiDynamicScheduler(n_items, acc_chunk=64)
-        for name in rates:
-            s.add_worker(name, WorkerKind.ACC if "acc" in name else WorkerKind.CC)
+    """The runtime's wall-clock engines over thread units with sleep work."""
 
-        def work(rate):
+    def _run(self, rates, engine, n_items=400, meet=None):
+        # every unit sleeps chunk.size / rate per chunk and records the
+        # indices it ran and the [start, end) of its work; a unit named in
+        # ``meet`` first waits at its barrier, on its first chunk only
+        lock = threading.Lock()
+        ran, spans = [], []
+        meet = dict(meet or {})
+        rt = HeteroRuntime(clock=WallClock())
+
+        def work(name, rate):
             def fn(chunk):
+                barrier = meet.pop(name, None)
+                if barrier is not None:
+                    barrier.wait()
+                t0 = time.perf_counter()
                 time.sleep(chunk.size / rate)
+                t1 = time.perf_counter()
+                with lock:
+                    ran.extend(chunk.indices())
+                    spans.append((t0, t1))
             return fn
 
-        eng = engine_cls(s, {n: work(r) for n, r in rates.items()}, **kw)
-        return eng.run()
+        for name, rate in rates.items():
+            kind = WorkerKind.ACC if "acc" in name else WorkerKind.CC
+            rt.register_unit(name, kind, work_fn=work(name, rate))
+        rep = rt.parallel_for(num_items=n_items, policy="multidynamic",
+                              engine=engine, acc_chunk=64)
+        return rep, sorted(ran), sorted(spans)
 
     def test_async_engine_completes_all(self):
-        rep = self._run(AsyncEngine, {"acc0": 8e4, "acc1": 8e4, "cc0": 1e4})
+        rep, ran, _ = self._run({"acc0": 8e4, "acc1": 8e4, "cc0": 1e4},
+                                "interrupt")
         assert rep.items == 400
+        assert ran == list(range(400))
+        assert rep.coverage[0][0] == 0 and rep.coverage[-1][1] == 400
+        for (a, b), (c, d) in zip(rep.coverage, rep.coverage[1:]):
+            assert b == c, f"gap or overlap at {b}:{c}"
 
-    def test_async_beats_polling_with_heterogeneous_units(self):
+    def test_interrupt_overlaps_units_polling_serialises(self):
         rates = {"acc0": 8e4, "acc1": 8e4, "cc0": 2e4, "cc1": 2e4}
-        rep_async = self._run(AsyncEngine, rates)
-        rep_poll = self._run(PollingEngine, rates)
-        # paper claim: interrupts (async) beat busy-wait on multi-unit runs
-        assert rep_async.throughput > rep_poll.throughput
+        # interrupt: an ACC and a CC unit's first chunks meet at a barrier,
+        # which can only pass if the two run at once
+        barrier = threading.Barrier(2, timeout=30)
+        rep, ran, _ = self._run(rates, "interrupt",
+                                meet={"acc0": barrier, "cc0": barrier})
+        assert rep.items == 400 and ran == list(range(400))
+        # polling: one driver thread runs every chunk, so no two overlap
+        rep, ran, spans = self._run(rates, "polling")
+        assert rep.items == 400 and ran == list(range(400))
+        assert len(spans) == rep.chunks
+        for (_, end), (start, _) in zip(spans, spans[1:]):
+            assert end <= start, (end, start)
 
     def test_work_distribution_favours_fast_units(self):
-        rep = self._run(AsyncEngine, {"acc0": 1e5, "cc0": 1e4})
+        rep, _, _ = self._run({"acc0": 1e5, "cc0": 1e4}, "interrupt")
         assert rep.per_worker_items["acc0"] > rep.per_worker_items["cc0"]
 
 

@@ -359,6 +359,28 @@ class TestLoadgenSmoke:
         assert metrics["p99_latency_s"] >= metrics["p50_latency_s"] > 0
         assert metrics["deadline_hit_rate"] == 1.0
 
+    @pytest.mark.parametrize("mode", ["static", "continuous"])
+    @pytest.mark.parametrize("policy", ["fifo", "cost"])
+    def test_every_policy_and_mode_completes_every_request(
+            self, served, policy, mode):
+        from repro.serving import ServingEngine
+
+        cfg, m, params = served
+        trace = make_trace(seed=0, n=6, rate=200.0, arrival="poisson",
+                           vocab_size=cfg.vocab_size, prompt_lens=(2, 8),
+                           gen_lens=(2, 8))
+        eng = ServingEngine(m, params, slots=2, max_len=48, mode=mode,
+                            policy=policy)
+        metrics = run_trace(eng, trace, time_scale=0.0)
+        assert set(metrics) == set(METRIC_KEYS)
+        assert metrics["completed"] == 6
+        assert metrics["failed"] == 0 and metrics["shed"] == 0
+        assert set(eng.results) == {t.request.rid for t in trace}
+        for t in trace:
+            got = eng.results[t.request.rid]
+            assert len(got.tokens) == t.request.max_new_tokens
+        assert metrics["p99_latency_s"] >= metrics["p50_latency_s"] > 0
+
     def test_mid_run_submissions_are_served(self, served):
         """submit() racing run(): every admitted request completes
         exactly once (the queue-snapshot lock)."""
