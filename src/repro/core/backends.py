@@ -55,7 +55,8 @@ import threading
 import time
 from collections import deque
 from dataclasses import dataclass, field
-from typing import Any, Callable, Dict, List, Mapping, Optional, Sequence, Tuple, Union
+from typing import (Any, Callable, Collection, Dict, List, Mapping, Optional, Sequence,
+                    Tuple, Union)
 
 from .elastic import ElasticEvent
 from .scheduler import Chunk
@@ -244,6 +245,13 @@ class BackendUnit:
     work itself: completion is reported by posting a
     :class:`CompletionRecord` to the bus.
 
+    A unit whose class sets :attr:`keep_between_runs` holds only a thread
+    and a queue of this process: a
+    :class:`~repro.core.runtime.HeteroRuntime` keeps it started between
+    runs, and ``start(bus)`` on a :attr:`running` unit only re-points it
+    at the new run's bus.  Other units (a process pool, a connection) are
+    started and closed by every run.
+
     Units that coalesce submissions (``capacity > 1``, e.g. a
     :class:`~repro.core.transport.RemoteUnit` with ``batch_frames > 1``)
     may buffer submits; the engine calls :meth:`flush` after each
@@ -257,11 +265,18 @@ class BackendUnit:
     #: where the unit computes, as ``jax.Device.platform`` names it; every
     #: unit but a :class:`JaxDeviceUnit` on an accelerator runs on the host
     platform = "cpu"
+    #: a runtime may keep the unit started from one run to the next
+    keep_between_runs = False
 
     def __init__(self, name: str) -> None:
         self.name = name
         self._bus: Optional[CompletionBus] = None
         self.dispatch_latencies: List[float] = []
+
+    @property
+    def running(self) -> bool:
+        """Started and not closed since: ``start`` would only re-point it."""
+        return self._bus is not None
 
     # -- lifecycle ----------------------------------------------------------
     def start(self, bus: CompletionBus) -> None:
@@ -275,7 +290,9 @@ class BackendUnit:
     def flush(self) -> None:
         """Push out any buffered submissions (no-op for unbatched units)."""
 
-    def close(self) -> None:
+    def close(self, wait: bool = True) -> None:
+        """End the unit; ``wait=False`` signals its thread to end and
+        does not join it (a kept unit's runtime being collected)."""
         self._bus = None
 
     # -- shared helpers -----------------------------------------------------
@@ -318,6 +335,7 @@ class InlineUnit(BackendUnit):
     """
 
     kind_name = "inline"
+    keep_between_runs = True
 
     def submit(self, chunk: Chunk, work_fn: WorkFn) -> None:
         self._execute(chunk, work_fn, time.perf_counter())
@@ -332,11 +350,16 @@ class ThreadUnit(BackendUnit):
     """
 
     kind_name = "thread"
+    keep_between_runs = True
 
     def __init__(self, name: str) -> None:
         super().__init__(name)
         self._queue: Optional[queue.Queue] = None
         self._thread: Optional[threading.Thread] = None
+
+    @property
+    def running(self) -> bool:
+        return self._thread is not None and self._thread.is_alive()
 
     def start(self, bus: CompletionBus) -> None:
         super().start(bus)
@@ -348,24 +371,33 @@ class ThreadUnit(BackendUnit):
             self._thread.start()
 
     def _worker(self) -> None:
+        get = self._queue.get
         while True:
-            item = self._queue.get()
+            item = get()
             if item is None:
                 return
-            submitted, chunk, work_fn = item
-            self._execute(chunk, work_fn, submitted)
+            self._execute(*item)
+            del item  # a kept thread holds no chunk or work_fn between runs
 
     def submit(self, chunk: Chunk, work_fn: WorkFn) -> None:
         assert self._queue is not None, f"unit {self.name!r} not started"
-        self._queue.put((time.perf_counter(), chunk, work_fn))
+        self._queue.put((chunk, work_fn, time.perf_counter()))
 
-    def close(self) -> None:
-        if self._thread is not None and self._thread.is_alive():
-            self._queue.put(None)
-            self._thread.join(timeout=10.0)
+    def close(self, wait: bool = True) -> None:
+        _end_thread(self._thread, self._queue, wait)
         self._thread = None
         self._queue = None
         super().close()
+
+
+def _end_thread(thread: Optional[threading.Thread], q: Optional[queue.Queue],
+                wait: bool) -> None:
+    """Tell a unit's queue-fed thread to end; join it if ``wait`` (and it
+    is not the calling thread)."""
+    if thread is not None and thread.is_alive():
+        q.put(None)
+        if wait and thread is not threading.current_thread():
+            thread.join(timeout=10.0)
 
 
 def _cpu_only_child() -> None:
@@ -503,6 +535,7 @@ class JaxDeviceUnit(BackendUnit):
     """
 
     kind_name = "jax"
+    keep_between_runs = True
 
     def __init__(self, name: str, device=0) -> None:
         super().__init__(name)
@@ -531,6 +564,10 @@ class JaxDeviceUnit(BackendUnit):
     def platform(self) -> str:
         return self._device.platform
 
+    @property
+    def running(self) -> bool:
+        return self._waiter is not None and self._waiter.is_alive()
+
     def start(self, bus: CompletionBus) -> None:
         super().start(bus)
         if self._waiter is None or not self._waiter.is_alive():
@@ -542,32 +579,38 @@ class JaxDeviceUnit(BackendUnit):
             self._waiter.start()
 
     def _wait_loop(self) -> None:
+        get = self._waitq.get
         while True:
-            item = self._waitq.get()
+            item = get()
             if item is None:
                 return
-            submitted, dispatched, chunk, out, error, work = item
-            copied = None
+            self._finish(*item)
+            del item  # a kept waiter holds no chunk or result between runs
+
+    def _finish(self, submitted: float, dispatched: float, chunk: Chunk, out,
+                error: Optional[BaseException], work: Optional[int]) -> None:
+        """Start the chunk's host copies, wait for it, post its completion."""
+        copied = None
+        if error is None:
+            try:
+                copied = self._start_host_copy(out)   # outside the span
+            except BaseException as exc:
+                error = exc
+        with span("eneac.acc_wait", unit=self.name, start=chunk.start,
+                  host_copy_bytes=copied or 0):
             if error is None:
                 try:
-                    copied = self._start_host_copy(out)   # outside the span
+                    self._jax.block_until_ready(out)
                 except BaseException as exc:
                     error = exc
-            with span("eneac.acc_wait", unit=self.name, start=chunk.start,
-                      host_copy_bytes=copied or 0):
-                if error is None:
-                    try:
-                        self._jax.block_until_ready(out)
-                    except BaseException as exc:
-                        error = exc
-                t_end = time.perf_counter()    # before the span closes
-            self._post(CompletionRecord(
-                unit=self.name, chunk=chunk, elapsed=t_end - dispatched,
-                dispatch_latency=dispatched - submitted, error=error,
-                result=out, work=work, host_copy_bytes=copied,
-                submitted_ns=_ns(submitted), enqueued_ns=_ns(dispatched),
-                ready_ns=_ns(t_end),
-            ))
+            t_end = time.perf_counter()    # before the span closes
+        self._post(CompletionRecord(
+            unit=self.name, chunk=chunk, elapsed=t_end - dispatched,
+            dispatch_latency=dispatched - submitted, error=error,
+            result=out, work=work, host_copy_bytes=copied,
+            submitted_ns=_ns(submitted), enqueued_ns=_ns(dispatched),
+            ready_ns=_ns(t_end),
+        ))
 
     def _start_host_copy(self, out) -> Optional[int]:
         """Start the host copy of each ``jax.Array`` leaf of ``out``; the
@@ -591,10 +634,8 @@ class JaxDeviceUnit(BackendUnit):
         dispatched = time.perf_counter()
         self._waitq.put((submitted, dispatched, chunk, out, error, close_work()))
 
-    def close(self) -> None:
-        if self._waiter is not None and self._waiter.is_alive():
-            self._waitq.put(None)
-            self._waiter.join(timeout=10.0)
+    def close(self, wait: bool = True) -> None:
+        _end_thread(self._waiter, self._waitq, wait)
         self._waiter = None
         self._waitq = None
         super().close()
@@ -726,6 +767,12 @@ class BackendEngine:
     ``phases`` times the dispatcher's phases (and opens their ``eneac.*``
     spans); :meth:`timeline` returns them with one
     :class:`~repro.core.trace.ChunkTimes` per completed chunk.
+
+    ``keep`` names the units the caller keeps between runs: a run that
+    ends, or raises a work-function error once its chunks have drained,
+    leaves them running.  Any other exception closes them too, since
+    chunks may still be in flight on them.  A kept unit that leaves,
+    is quarantined or is lost is closed as any other.
     """
 
     def __init__(
@@ -740,6 +787,7 @@ class BackendEngine:
         join_backend: Optional[Callable[[ElasticEvent], BackendUnit]] = None,
         straggler=None,
         phases: Optional[Phases] = None,
+        keep: Collection[str] = (),
     ) -> None:
         self.sched = sched
         self.fns: Dict[str, Optional[WorkFn]] = dict(fns)
@@ -752,6 +800,9 @@ class BackendEngine:
         self.bus = CompletionBus()
         self.events: List[dict] = []          # RunReport.events entries
         self._own_units = set()               # started here -> closed here
+        self._keep = frozenset(keep)          # ... unless kept, on a clean end
+        self._units_started = 0               # Timeline.units_started
+        self._units_kept = 0                  # Timeline.units_kept
         self._all_units = dict(units)         # includes retired units (stats)
         self._inflight: Dict[str, int] = {}   # unit -> chunks in flight
         self._last_caps: Dict[str, int] = {}  # capacity last synced to sched
@@ -848,6 +899,7 @@ class BackendEngine:
                     self._retire(ev.unit)
             else:
                 unit = self.join_backend(ev)
+                self._count_start(unit)
                 unit.start(self.bus)
                 self.units[ev.unit] = unit
                 self._all_units[ev.unit] = unit
@@ -969,6 +1021,12 @@ class BackendEngine:
             self._retire(name)
         det.forget(name)
 
+    def _count_start(self, unit: BackendUnit) -> None:
+        if unit.running:
+            self._units_kept += 1
+        else:
+            self._units_started += 1
+
     # -- the loop -----------------------------------------------------------
     def run(self) -> float:
         """Drive the space to completion; returns the wall makespan."""
@@ -976,11 +1034,13 @@ class BackendEngine:
         set_cap = getattr(self.sched, "set_capacity", None)
         with self.phases("units_start"):
             for name, unit in self.units.items():
+                self._count_start(unit)
                 unit.start(self.bus)
                 self._own_units.add(name)
                 self._last_caps[name] = self._capacity(name)
                 if set_cap is not None:
                     set_cap(name, self._last_caps[name])
+        clean = False
         try:
             self._apply_due_events()
             self._dispatch_idle()
@@ -1011,10 +1071,11 @@ class BackendEngine:
                     self._apply_due_events()
                     continue
                 break
+            clean = True
         finally:
             with self.phases("units_close"):
                 for name, unit in self.units.items():
-                    if name in self._own_units:
+                    if name in self._own_units and not (clean and name in self._keep):
                         unit.close()
         if self._errors:
             raise self._errors[0]
@@ -1025,6 +1086,7 @@ class BackendEngine:
         return Timeline(
             phase_s=self.phases.seconds, chunks=self._chunk_times,
             wakeups=self._wakeups, drained=self._drained,
+            units_started=self._units_started, units_kept=self._units_kept,
         )
 
     def per_worker_work(self) -> Optional[Dict[str, int]]:

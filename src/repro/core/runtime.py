@@ -67,6 +67,7 @@ from __future__ import annotations
 import heapq
 import threading
 import time
+import weakref
 from dataclasses import dataclass
 from typing import Callable, Dict, List, Mapping, Optional, Sequence, Tuple, Union
 
@@ -439,16 +440,93 @@ def _build_report(
     )
 
 
+class _UnitPool:
+    """The started backends a runtime keeps between wall-clock runs.
+
+    Only units whose class sets
+    :attr:`~repro.core.backends.BackendUnit.keep_between_runs` enter it.
+    One run at a time holds the pool (:meth:`take`); a run that finds it
+    held (a nested or concurrent call) builds units of its own.  At the
+    end of the run (:meth:`give_back`) a unit that was closed during it,
+    or dropped meanwhile, is closed and leaves the pool.
+    """
+
+    def __init__(self) -> None:
+        self._lock = threading.Lock()
+        self._units: Dict[str, BackendUnit] = {}
+        self._busy = False
+        self._dropped: set = set()   # names dropped while a run held the pool
+
+    def take(self) -> Optional[Dict[str, BackendUnit]]:
+        """The kept units, for the caller's run to use and add to; None
+        when another run holds the pool."""
+        with self._lock:
+            if self._busy:
+                return None
+            self._busy = True
+            return dict(self._units)
+
+    def give_back(self, used: Mapping[str, BackendUnit]) -> None:
+        """End the holder's run; ``used`` are the kept units it ran on."""
+        with self._lock:
+            self._busy = False
+            self._units.update(used)
+            gone = self._dropped | {n for n, u in used.items() if not u.running}
+            self._dropped = set()
+            for name in gone:
+                unit = self._units.pop(name, None)
+                if unit is not None:
+                    unit.close()
+
+    def drop(self, name: str) -> None:
+        """Close ``name``'s kept unit and forget it; while a run holds the
+        pool, that run's :meth:`give_back` does."""
+        with self._lock:
+            if self._busy:
+                self._dropped.add(name)
+                return
+            unit = self._units.pop(name, None)
+            if unit is not None:
+                unit.close()
+
+    def close(self, wait: bool = True) -> None:
+        with self._lock:
+            units, self._units = self._units, {}
+            for unit in units.values():
+                unit.close(wait=wait)
+
+
 # ---------------------------------------------------------------------------
 # the runtime
 # ---------------------------------------------------------------------------
 class HeteroRuntime:
-    """One registry of heterogeneous units, many ways to run them."""
+    """One registry of heterogeneous units, many ways to run them.
+
+    A wall-clock ``"interrupt"`` run keeps the backends of the registered
+    units whose class allows it (threads and queues of this process:
+    ``ThreadUnit``, ``JaxDeviceUnit``, ``InlineUnit``) started for the
+    next run.  :meth:`close`, the end of a ``with`` block, or the
+    runtime being collected ends them.
+    """
 
     def __init__(self, *, clock=None, cost_model: Optional[CostModel] = None) -> None:
         self.clock = clock if clock is not None else WallClock()
         self.cost_model = cost_model
         self._units: Dict[str, UnitSpec] = {}
+        self._pool = _UnitPool()
+        # over the pool, not the runtime: a dropped runtime's threads end
+        weakref.finalize(self, self._pool.close, False)
+
+    def close(self) -> None:
+        """End the kept units' threads.  The runtime stays usable: the
+        next run starts its units again."""
+        self._pool.close()
+
+    def __enter__(self) -> "HeteroRuntime":
+        return self
+
+    def __exit__(self, *exc) -> None:
+        self.close()
 
     # -- unit registry ------------------------------------------------------
     def register_unit(
@@ -478,10 +556,13 @@ class HeteroRuntime:
 
         Only affects *future* runs — a run in flight resolved its specs
         at call time and retires units through the elastic path instead.
+        The unit's kept backend is closed (by a run in flight when it
+        ends).
         Raises ``KeyError`` for unknown names so a double-drain is loud.
         """
         if name not in self._units:
             raise KeyError(f"unknown unit {name!r}")
+        self._pool.drop(name)
         return self._units.pop(name)
 
     def set_speed(self, name: str, speed: float) -> None:
@@ -802,7 +883,7 @@ class HeteroRuntime:
                         sched, specs, fns, engine, poll_interval,
                         elastic=elastic_events, expected=sp.num_items,
                         default_fn=work_fn, backend=backend, straggler=straggler,
-                        phases=phases,
+                        phases=phases, pool=self._pool,
                     )
             if self.cost_model is not None:
                 # every run teaches the model — including multidynamic warmups,
@@ -854,31 +935,46 @@ class HeteroRuntime:
         backend: Optional[Union[str, BackendUnit]] = None,
         straggler: Optional[StragglerDetector] = None,
         phases: Optional[Phases] = None,
+        pool: Optional[_UnitPool] = None,
     ) -> RunReport:
         if engine == "interrupt":
             # Event-driven dispatch over real backend units: each unit's
             # chunks execute on its own backend (dedicated thread by
             # default), completions arrive on a condition variable, and
             # elastic membership changes apply between dispatches under
-            # the tracked scheduler's lock.
+            # the tracked scheduler's lock.  The registered units' backends
+            # come from ``pool`` where their class allows, started by an
+            # earlier run; a call-level ``backend`` builds units per run.
             phases = phases if phases is not None else Phases()
-            with phases("units_start"):
-                units = {
-                    s.name: make_backend(
-                        backend if backend is not None else s.backend, s.name
+            pooled: Optional[Dict[str, BackendUnit]] = None
+            kept: Dict[str, BackendUnit] = {}
+            try:
+                with phases("units_start"):
+                    if pool is not None and backend is None:
+                        pooled = pool.take()
+                    units: Dict[str, BackendUnit] = {}
+                    for s in specs:
+                        unit = pooled.get(s.name) if pooled is not None else None
+                        if unit is None:
+                            unit = make_backend(
+                                backend if backend is not None else s.backend, s.name
+                            )
+                        units[s.name] = unit
+                    if pooled is not None:
+                        kept = {n: u for n, u in units.items() if u.keep_between_runs}
+                    eng = BackendEngine(
+                        sched, fns, units,
+                        expected=expected, elastic=elastic, default_fn=default_fn,
+                        join_backend=lambda ev: make_backend(
+                            backend if not isinstance(backend, BackendUnit) else None,
+                            ev.unit,
+                        ),
+                        straggler=straggler, phases=phases, keep=kept,
                     )
-                    for s in specs
-                }
-                eng = BackendEngine(
-                    sched, fns, units,
-                    expected=expected, elastic=elastic, default_fn=default_fn,
-                    join_backend=lambda ev: make_backend(
-                        backend if not isinstance(backend, BackendUnit) else None,
-                        ev.unit,
-                    ),
-                    straggler=straggler, phases=phases,
-                )
-            wall = eng.run()
+                wall = eng.run()
+            finally:
+                if pooled is not None:
+                    pool.give_back(kept)
             # "dead" (heartbeat conviction) is as much a loss as "lost"
             # (EOF): either way a unit departed with work requeued, so an
             # under-covered run must raise instead of reporting quietly.

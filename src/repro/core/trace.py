@@ -148,3 +148,7 @@ class Timeline:
     chunks: List[ChunkTimes]
     wakeups: int     # returns of CompletionBus.wait
     drained: int     # completions taken off the bus
+    # units whose thread this run started (joins included), and units it
+    # was handed already running (kept by the runtime since an earlier run)
+    units_started: int = 0
+    units_kept: int = 0

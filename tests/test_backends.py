@@ -970,3 +970,265 @@ class TestKernelRuntimeParity:
                               backend="threads")
         assert rep.items == space.num_items
         assert np.array_equal(out, expect), "banded stencil diverged from ref"
+
+
+# ---------------------------------------------------------------------------
+# kept units: a runtime keeps its in-process units started between runs
+# ---------------------------------------------------------------------------
+def _unit_threads(prefix):
+    """Live ``eneac-unit-*`` / ``eneac-jaxwait-*`` threads of units whose
+    names start with ``prefix``, by thread name."""
+    return {t.name: t for t in threading.enumerate()
+            if t.name.startswith((f"eneac-unit-{prefix}",
+                                  f"eneac-jaxwait-{prefix}"))}
+
+
+def _wait_for_no_threads(prefix, timeout=10.0):
+    deadline = time.perf_counter() + timeout
+    while _unit_threads(prefix) and time.perf_counter() < deadline:
+        time.sleep(0.01)
+    return _unit_threads(prefix)
+
+
+def _prefix():
+    import uuid
+
+    return f"k{uuid.uuid4().hex[:8]}-"
+
+
+class TestKeptUnits:
+    N = 96
+
+    def _mixed_runtime(self, prefix, cc_work=lambda chunk: None):
+        """4 ``jax:0`` ACC units and 4 ``"thread"`` CC units."""
+        import jax.numpy as jnp
+
+        x = jnp.ones((8, 8), jnp.float32)
+        rt = HeteroRuntime()
+        for i in range(4):
+            rt.register_unit(f"{prefix}acc{i}", WorkerKind.ACC, backend="jax:0",
+                             work_fn=lambda c: x * c.start)
+            rt.register_unit(f"{prefix}cc{i}", WorkerKind.CC, backend="thread",
+                             work_fn=cc_work)
+        return rt
+
+    def test_consecutive_runs_reuse_each_units_thread(self):
+        p = _prefix()
+        run = [0]
+        idents = {}   # (run, unit) -> threads that ran the unit's chunks
+
+        def cc_work(chunk):
+            idents.setdefault((run[0], chunk.worker), set()).add(threading.get_ident())
+
+        rt = self._mixed_runtime(p, cc_work)
+        counts, threads = [], []
+        for run[0] in range(2):
+            rep = rt.parallel_for(num_items=self.N, acc_chunk=8)
+            assert_exact_tiling(rep.coverage, self.N)
+            counts.append((rep.timeline.units_started, rep.timeline.units_kept))
+            threads.append(_unit_threads(p))
+        assert counts == [(8, 0), (0, 8)]
+        # every unit keeps its one thread: the same Thread objects, alive
+        assert len(threads[0]) == 8 and threads[0] == threads[1]
+        assert all(t.is_alive() for t in threads[1].values())
+        assert {u for _, u in idents} == {f"{p}cc{i}" for i in range(4)}
+        for (_, unit), ids in idents.items():
+            assert ids == {threads[1][f"eneac-unit-{unit}"].ident}, unit
+        rt.close()
+
+    @pytest.mark.parametrize("how", ["close", "with", "gc"])
+    def test_threads_end_when_the_runtime_ends(self, how):
+        import gc
+
+        p = _prefix()
+        if how == "with":
+            with self._mixed_runtime(p) as rt:
+                rt.parallel_for(num_items=self.N, acc_chunk=8)
+                assert len(_unit_threads(p)) == 8
+        else:
+            rt = self._mixed_runtime(p)
+            rt.parallel_for(num_items=self.N, acc_chunk=8)
+            assert len(_unit_threads(p)) == 8
+            if how == "close":
+                rt.close()
+            else:
+                del rt
+                gc.collect()
+        assert not _wait_for_no_threads(p)
+
+    def test_runtime_closes_and_restarts(self):
+        p = _prefix()
+        rt = self._mixed_runtime(p)
+        rt.parallel_for(num_items=self.N, acc_chunk=8)
+        rt.close()
+        assert not _unit_threads(p)
+        rep = rt.parallel_for(num_items=self.N, acc_chunk=8)
+        assert_exact_tiling(rep.coverage, self.N)
+        assert (rep.timeline.units_started, rep.timeline.units_kept) == (8, 0)
+        rt.close()
+
+    def test_dropped_runtimes_leave_no_threads(self):
+        p = _prefix()
+        for _ in range(50):
+            rt = HeteroRuntime()
+            for i in range(2):
+                rt.register_unit(f"{p}cc{i}", WorkerKind.CC, backend="thread",
+                                 work_fn=lambda c: None)
+            rt.parallel_for(num_items=16, acc_chunk=4)
+            del rt
+        deadline = time.perf_counter() + 10.0
+        while len(_unit_threads(p)) > 2 and time.perf_counter() < deadline:
+            time.sleep(0.01)
+        assert len(_unit_threads(p)) <= 2
+
+    @pytest.mark.parametrize("how", ["leave", "straggler", "deregister",
+                                     "deregister_mid_run"])
+    def test_a_departed_unit_is_closed_and_rebuilt(self, how):
+        from repro.core.straggler import StragglerDetector
+
+        p = _prefix()
+        names = [f"{p}u{i}" for i in range(3)]
+        gone = names[2]
+        slow = {"on": how == "straggler", "n": 0}
+        lock = threading.Lock()
+        mid_run = {"armed": how == "deregister_mid_run", "spec": None}
+
+        def make_work(rec):
+            def work(chunk):
+                if mid_run["armed"] and chunk.start == 0:
+                    # a registry change during a run only affects later runs
+                    mid_run["armed"] = False
+                    mid_run["spec"] = rt.deregister_unit(gone)
+                per_item = 3e-4
+                if slow["on"] and chunk.worker == gone:
+                    with lock:
+                        slow["n"] += 1
+                        if slow["n"] > 2:
+                            per_item = 3e-3
+                time.sleep(per_item * chunk.size)
+                rec(chunk)
+            return work
+
+        rt = HeteroRuntime()
+        for n in names:
+            rt.register_unit(n, WorkerKind.CC, backend="thread")
+        n_items = 960
+        kw = dict(num_items=n_items, policy="multidynamic", acc_chunk=8,
+                  scheduler_kwargs=dict(min_cc_chunk=8, max_cc_chunk=8))
+        recs, reps = [], []
+        for k in range(3):
+            rec = Recorder()
+            extra = {}
+            if k == 0 and how == "leave":
+                extra["elastic"] = ElasticSchedule().leave(0.005, gone)
+            if k == 0 and how == "straggler":
+                extra["straggler"] = StragglerDetector(alpha=0.6, threshold=4.0,
+                                                       patience=3)
+            reps.append(rt.parallel_for(make_work(rec), **kw, **extra))
+            recs.append(rec)
+            if k == 0:
+                slow["on"] = False
+                spec = rt.deregister_unit(gone) if how == "deregister" else mid_run["spec"]
+                if spec is not None:
+                    rt.register_unit(gone, spec.kind, backend=spec.backend)
+                # the departed unit's thread was joined; the others run on
+                assert set(_unit_threads(p)) == {f"eneac-unit-{n}" for n in names[:2]}
+        if how in ("leave", "straggler"):
+            assert [e["action"] for e in reps[0].events] == [how]
+            assert reps[0].events[0]["unit"] == gone
+        for rep, rec in zip(reps, recs):
+            assert_exact_tiling(rep.coverage, n_items)
+            rec.assert_exactly_once(n_items)
+        counts = [(r.timeline.units_started, r.timeline.units_kept) for r in reps]
+        assert counts == [(3, 0), (1, 2), (0, 3)]
+        assert reps[1].per_worker_items[gone] > 0
+        rt.close()
+
+    def test_a_work_fn_error_leaves_the_next_run_whole(self):
+        p = _prefix()
+        rt = self._mixed_runtime(p)
+        fail = {"on": True}
+
+        def work(chunk):
+            if fail["on"] and chunk.start <= 40 < chunk.stop:
+                raise ValueError("chunk at 40")
+
+        with pytest.raises(ValueError, match="chunk at 40"):
+            rt.parallel_for(work, num_items=self.N, acc_chunk=8)
+        fail["on"] = False
+        rec = Recorder()
+        rep = rt.parallel_for(rec, num_items=self.N, acc_chunk=8)
+        assert_exact_tiling(rep.coverage, self.N)
+        rec.assert_exactly_once(self.N)
+        assert (rep.timeline.units_started, rep.timeline.units_kept) == (0, 8)
+        rt.close()
+
+    def test_a_nested_run_builds_its_own_units(self):
+        p = _prefix()
+        rt = HeteroRuntime()
+        for i in range(2):
+            rt.register_unit(f"{p}cc{i}", WorkerKind.CC, backend="thread")
+        inner = []
+
+        def outer_work(chunk):
+            if chunk.start == 0:   # the pool is held by the outer run
+                rec = Recorder()
+                inner.append((rt.parallel_for(rec, num_items=16, acc_chunk=4), rec))
+
+        rt.parallel_for(outer_work, num_items=32, acc_chunk=4)
+        (rep, rec), = inner
+        rec.assert_exactly_once(16)
+        assert (rep.timeline.units_started, rep.timeline.units_kept) == (2, 0)
+        rep2 = rt.parallel_for(lambda c: None, num_items=32, acc_chunk=4)
+        assert (rep2.timeline.units_started, rep2.timeline.units_kept) == (0, 2)
+        assert len(_unit_threads(p)) == 2
+        rt.close()
+
+    @pytest.mark.parametrize("case", ["call_backend", "process"])
+    def test_per_run_units_start_and_close_every_run(self, case):
+        p = _prefix()
+        rt = HeteroRuntime()
+        if case == "process":
+            unit = ProcessPoolUnit(f"{p}p0")
+            rt.register_unit(unit.name, WorkerKind.CC, work_fn=_sum_indices,
+                             backend=unit)
+            n_units, kw = 1, {}
+        else:
+            for i in range(3):
+                rt.register_unit(f"{p}cc{i}", WorkerKind.CC, work_fn=_sum_indices)
+            n_units, kw = 3, {"backend": "thread"}
+        for _ in range(2):
+            rep = rt.parallel_for(num_items=32, acc_chunk=8, **kw)
+            assert_exact_tiling(rep.coverage, 32)
+            assert (rep.timeline.units_started, rep.timeline.units_kept) == (n_units, 0)
+            assert not _unit_threads(p)
+            if case == "process":
+                assert not unit.running and unit._pool is None
+
+    @pytest.mark.parametrize("backend", ["jax:0", "thread"])
+    def test_a_kept_unit_holds_no_reference_to_its_last_result(self, backend):
+        import gc
+        import weakref
+
+        import jax.numpy as jnp
+
+        x = jnp.ones((16, 16), jnp.float32)
+        refs = []
+
+        def work(chunk):
+            out = x * chunk.start if backend == "jax:0" else Chunk(0, 1, "r")
+            refs.append(weakref.ref(out))
+            return out
+
+        p = _prefix()
+        rt = HeteroRuntime()
+        rt.register_unit(f"{p}u0", WorkerKind.ACC, backend=backend, work_fn=work)
+        rep = rt.parallel_for(num_items=32, acc_chunk=8)
+        assert rep.items == 32 and len(refs) == 4
+        assert len(_unit_threads(p)) == 1   # kept, idle
+        deadline = time.perf_counter() + 10.0
+        while any(r() is not None for r in refs) and time.perf_counter() < deadline:
+            gc.collect()
+            time.sleep(0.01)
+        assert all(r() is None for r in refs)
+        rt.close()
