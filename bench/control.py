@@ -8,8 +8,10 @@ window, and reads the number the check compares (the lower reading is the
 largest of these).  For each of ``--control-seeds`` it puts the control, the
 configuration's reference in the next lower precision, in the program's
 place on the first ACC device and reads the same number (the upper reading
-is the smallest of these).  The benchmark's own runs never run this.  It
-needs the chip, and runs everything in one process.
+is the smallest of these); for a problem that sets ``PER_LOOP``, on the
+first loop of the window, beside that loop's reference.  The benchmark's
+own runs never run this.  It needs the chip, and runs everything in one
+process.
 """
 
 from __future__ import annotations
@@ -30,13 +32,15 @@ def _seeds(text: str):
 
 def control_reading(bench, workload: str, seed: int, device) -> float:
     """The compared number with the control in the program's place."""
-    from harness.cell import row_errors
+    from harness.cell import WARMUP_LOOPS, row_errors
 
     wl = bench.workload(workload)
     cfg = bench.config(wl["config"])
     problem = bench.problem(cfg["problem"])
     prob = problem.generate(cfg, seed)
-    return float(row_errors(problem.control(prob, device), problem.reference(prob)).max())
+    kw = {"loop": WARMUP_LOOPS} if getattr(problem, "PER_LOOP", False) else {}
+    return float(row_errors(problem.control(prob, device, **kw),
+                            problem.reference(prob, **kw)).max())
 
 
 def readings(root, workload: str, seeds, control_seeds, seconds: float, *,

@@ -64,7 +64,7 @@ def make(prob, acc_chunk: int):
         loop["result"][chunk.start:chunk.stop] = hotspot_rows_host(
             temp, power, chunk.start, chunk.stop, cfg, steps)
 
-    def begin_loop() -> None:
+    def begin_loop(_loop: int) -> None:   # every loop has the same inputs
         loop["result"] = np.full(temp.shape, np.nan, np.float32)
         loop["acc"] = {}
 

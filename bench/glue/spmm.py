@@ -66,7 +66,7 @@ def make(prob, acc_chunk: int):
             csr, x, chunk.start, chunk.stop)
         loop["cc"].append((chunk.start, chunk.stop))
 
-    def begin_loop() -> None:
+    def begin_loop(_loop: int) -> None:   # every loop has the same inputs
         loop.pop("result", None)
         for entry in buffers:
             if entry[1] is None or entry[1]() is None:

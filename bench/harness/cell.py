@@ -10,6 +10,15 @@ problem, placing operands on each ACC device, compiling and warming up.
 After the window, with the device state freed, the assembled results of a
 few loops drawn from the seed are compared with the configuration's plain
 float64 reference.
+
+Loops are numbered from 0, the first warm-up loop, on through the window,
+and the glue's ``begin_loop(loop)`` is told each loop's number.  A problem
+module whose answer changes from loop to loop declares ``PER_LOOP = True``:
+its inputs for every loop are made in ``generate``, before the window, and
+``begin_loop`` only hands that loop's inputs over, as a caller would.  The
+check then compares each sampled loop with ``reference(prob, loop=i)`` of
+its own number ``i``, one reference at a time.  Without the flag the
+answer is the same in every loop, and one ``reference(prob)`` serves all.
 """
 
 from __future__ import annotations
@@ -190,17 +199,17 @@ def run_cell(root, workload: str, seed: int, seconds: float, trace: bool, *,
             rt.register_unit(name, WorkerKind.CC, backend="thread",
                              work_fn=_annotated("bench.cc_chunk", glue["cc_work"]))
 
-        def one_loop():
+        def one_loop(loop: int):
             with TraceAnnotation("bench.loop"):
-                glue["begin_loop"]()
+                glue["begin_loop"](loop)
                 rep = rt.parallel_for(num_items=prob.rows, policy=mix["policy"],
                                       engine=mix["engine"], acc_chunk=mix["acc_chunk"])
                 with TraceAnnotation("bench.assemble"):
                     result, acc_spans = glue["assemble"]()
             return rep, result, acc_spans
 
-        for _ in range(WARMUP_LOOPS):
-            one_loop()
+        for loop in range(WARMUP_LOOPS):
+            one_loop(loop)
         compiles_setup, misses_setup = counter.compiles, counter.misses
 
         rng = random.Random(seed)
@@ -218,7 +227,7 @@ def run_cell(root, workload: str, seed: int, seconds: float, trace: bool, *,
                 with TraceAnnotation(trace_mod.WINDOW_SPAN):
                     while True:
                         a = time.perf_counter()
-                        rep, out, acc_spans = one_loop()
+                        rep, out, acc_spans = one_loop(WARMUP_LOOPS + len(times))
                         b = time.perf_counter()
                         times.append(b - a)
                         reports.append(rep)
@@ -228,10 +237,11 @@ def run_cell(root, workload: str, seed: int, seconds: float, trace: bool, *,
                         i = len(times) - 1
                         j = i if i < k else rng.randrange(i + 1)
                         if j < k:
+                            entry = (WARMUP_LOOPS + i, out, acc_spans)
                             if j < len(sample):
-                                sample[j] = (i, out, acc_spans)
+                                sample[j] = entry
                             else:
-                                sample.append((i, out, acc_spans))
+                                sample.append(entry)
                         if b >= deadline:
                             break
                 window_s = b - w0
@@ -248,9 +258,13 @@ def run_cell(root, workload: str, seed: int, seconds: float, trace: bool, *,
     gc.collect()
 
     t_ref = time.perf_counter()
-    ref = problem.reference(prob)
+    per_loop = getattr(problem, "PER_LOOP", False)
+    ref = None if per_loop else problem.reference(prob)
     errs, acc_err, cc_err, unwritten = [], 0.0, 0.0, 0
-    for _, out, spans in sample:
+    for loop, out, spans in sample:
+        if per_loop:
+            ref = None   # free the last loop's reference before the next
+            ref = problem.reference(prob, loop=loop)
         e = row_errors(out, ref)
         unwritten += int(np.isinf(e).sum())
         on_acc = np.zeros(e.shape[0], bool)
@@ -272,7 +286,9 @@ def run_cell(root, workload: str, seed: int, seconds: float, trace: bool, *,
     log.append(f"window: {len(times)} loops in {window_s:.3f} s; compiles in window "
                f"{compiles_window}; backend compiles in set-up {compiles_setup}, "
                f"cache misses {misses_setup}; reference {time.perf_counter() - t_ref:.3f} s")
-    log.append(f"compared loops {[i for i, _, _ in sample]}: max_row_rel_err on ACC "
+    log.append(f"compared loops {[i for i, _, _ in sample]} "
+               f"({'each with its own reference' if per_loop else 'with one reference'}): "
+               f"max_row_rel_err on ACC "
                f"rows {acc_err:.6e}, on CC rows {cc_err:.6e}")
 
     n = len(times)

@@ -7,6 +7,16 @@
 * a traffic mix is ``bench/traffic/<traffic>.json``;
 * a per-layer metric is read by ``bench/metrics/<name>.py``.
 
+A problem module gives ``generate(cfg, seed)``, ``reference(prob)``,
+``control(prob, device)`` and ``chunk_work(prob, start, stop)``; its glue
+gives ``make(prob, acc_chunk)``, whose closures include
+``begin_loop(loop)``, told the number of each loop from 0 (the first
+warm-up loop).  A problem whose answer changes from loop to loop sets
+``PER_LOOP = True``; its ``reference`` and ``control`` then take
+``loop=i`` and give loop ``i``'s answer.  Every loop's inputs are made in
+``generate``, outside the measured window, and ``begin_loop`` does only
+what a caller would do to hand them over.
+
 A new cell, mix or metric is new files and entries; nothing here changes.
 """
 

@@ -118,16 +118,16 @@ def test_a_result_buffer_is_reused_only_once_no_view_is_held(tiny_root):
     glue = load_file(tiny_root / "bench" / "glue" / "spmm.py")
     g = glue.make(spmm.generate(SMALL, 3), 64)
 
-    def next_result():
-        g["begin_loop"]()
+    def next_result(loop):
+        g["begin_loop"](loop)
         return g["assemble"]()[0]
 
-    held = [next_result() for _ in range(glue.BUFFERS + 2)]
+    held = [next_result(loop) for loop in range(glue.BUFFERS + 2)]
     for i, a in enumerate(held):
         assert not any(np.shares_memory(a, b) for b in held[i + 1:])
     dropped = held.pop(3)
     base = dropped.base
     del dropped
-    again = next_result()
+    again = next_result(len(held) + 1)
     assert again.base is base
     assert not any(np.shares_memory(again, b) for b in held)
