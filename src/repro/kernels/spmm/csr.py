@@ -17,6 +17,13 @@ take no trip, and a row longer than a tile takes several.
 
 The window and the loop sizes are static and ``start`` is not, so every
 call on a device reuses one compiled program.
+
+APPNP propagation (Klicpera et al., ICLR 2019) repeats the product with
+an operand that each step computes: Z ← (1 − α)·Â·Z + α·H.
+:func:`appnp_csr_window` is one step on a window, through the same trip
+loop.  The next operand is a donated buffer written in place, a masked
+fixed-shape slab at a time: :func:`write_window_rows` takes a window's
+rows, :func:`put_operand_rows` a block of rows put up from the host.
 """
 
 from __future__ import annotations
@@ -27,8 +34,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-__all__ = ["spmm_csr_window", "csr_window_start", "csr_window_tiles",
-           "CSR_TILE", "CSR_BLOCK"]
+__all__ = ["spmm_csr_window", "appnp_csr_window", "put_operand_rows",
+           "write_window_rows", "csr_window_start", "csr_window_tiles", "CSR_TILE", "CSR_BLOCK"]
 
 # Entries per trip and rows a trip may reach of the chip path (measured
 # on a TPU v5e: PERF.md, section 6).
@@ -53,6 +60,63 @@ def spmm_csr_window(
     The window must lie inside the matrix's rows (callers place it with
     :func:`csr_window_start`).
     """
+    return _window_rows(indptr, indices, data, x, start, window, tile, block)
+
+
+@functools.partial(jax.jit, static_argnames=("window", "alpha", "tile", "block"))
+def appnp_csr_window(
+    indptr: jax.Array,    # (R + 1,) int32 row pointers of Â
+    indices: jax.Array,   # (nnz,) int32 column of each stored entry
+    data: jax.Array,      # (nnz,) f32 value of each stored entry
+    z: jax.Array,         # (R, N) f32 operand of the step, left in HBM
+    h: jax.Array,         # (R, N) f32 teleport rows H, left in HBM
+    start,                # int32 scalar: first row of the window
+    *,
+    window: int,
+    alpha: float,
+    tile: int = CSR_TILE,
+    block: int = CSR_BLOCK,
+) -> jax.Array:
+    """One APPNP propagation step on a window: rows [start, start + window)
+    of (1 − α)·Â·Z + α·H, as a (window, N) f32 array.
+
+    The product takes :func:`spmm_csr_window`'s trip loop; the teleport
+    term reads the window's rows of H.
+    """
+    s = jnp.asarray(start, jnp.int32)
+    az = _window_rows(indptr, indices, data, z, s, window, tile, block)
+    return (1.0 - alpha) * az + alpha * jax.lax.dynamic_slice(h, (s, 0), (window, h.shape[1]))
+
+
+@functools.partial(jax.jit, donate_argnames=("nxt",))
+def put_operand_rows(nxt: jax.Array, rows: jax.Array, start, first, stop) -> jax.Array:
+    """``nxt`` with its rows [first, stop) replaced by those of ``rows``,
+    a block of rows put up from the host whose first row is row ``start``
+    of ``nxt``.  ``nxt`` is donated: written in place."""
+    return _write_rows(nxt, rows, start, first, stop)
+
+
+@functools.partial(jax.jit, donate_argnames=("nxt",))
+def write_window_rows(nxt: jax.Array, rows: jax.Array, start, first, stop) -> jax.Array:
+    """As :func:`put_operand_rows`, for a window's rows that the device
+    computed: a module of its own name, so that the profiler tells the two
+    apart."""
+    return _write_rows(nxt, rows, start, first, stop)
+
+
+def _write_rows(nxt, rows, start, first, stop):
+    """``nxt`` with rows [first, stop) of the slab at row ``start`` taken
+    from ``rows``: a masked update of that slab alone."""
+    s = jnp.asarray(start, jnp.int32)
+    r = s + jnp.arange(rows.shape[0], dtype=jnp.int32)
+    keep = (first <= r) & (r < stop)
+    cur = jax.lax.dynamic_slice(nxt, (s, 0), rows.shape)
+    return jax.lax.dynamic_update_slice(nxt, jnp.where(keep[:, None], rows, cur), (s, 0))
+
+
+def _window_rows(indptr, indices, data, x, start, window, tile, block):
+    """The trip loop of :func:`spmm_csr_window`: rows [start, start +
+    window) of A·X."""
     rows = indptr.shape[0] - 1
     nnz, n = indices.shape[0], x.shape[1]
     if not 0 < window <= rows:

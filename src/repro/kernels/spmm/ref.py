@@ -27,6 +27,7 @@ import jax.numpy as jnp
 __all__ = [
     "SpmmProblem", "make_problem", "spmm_dense_ref", "spmm_ell_ref",
     "BlockEll", "to_block_ell", "CsrProblem", "make_csr_problem", "spmm_csr_ref",
+    "normalized_adjacency", "appnp_propagate_ref",
 ]
 
 ROW_BLOCK = 8
@@ -201,3 +202,36 @@ def spmm_csr_ref(p: CsrProblem) -> jax.Array:
     dense = jnp.zeros((p.rows, p.n_cols), jnp.float32).at[rows, p.indices].add(p.data)
     with jax.default_matmul_precision("highest"):
         return dense @ jnp.asarray(p.rhs, jnp.float32)
+
+
+def normalized_adjacency(indptr: np.ndarray, indices: np.ndarray
+                         ) -> Tuple[np.ndarray, np.ndarray, np.ndarray]:
+    """Â = D̃^-1/2 (A + I) D̃^-1/2 in CSR (``indptr``, ``indices``, f32
+    ``data``), with A the binary matrix of the CSR pattern (a repeated
+    entry counts once, a stored diagonal entry is I's) and D̃ the row sums
+    of A + I."""
+    n = len(indptr) - 1
+    rows = np.repeat(np.arange(n, dtype=np.int64), np.diff(indptr))
+    key = np.unique(np.concatenate([rows * n + indices, np.arange(n, dtype=np.int64) * (n + 1)]))
+    r, c = np.divmod(key, n)
+    d = 1.0 / np.sqrt(np.bincount(r, minlength=n).astype(np.float64))
+    out_ptr = np.zeros(n + 1, np.int64)
+    np.cumsum(np.bincount(r, minlength=n), out=out_ptr[1:])
+    return out_ptr, c.astype(np.int32), (d[r] * d[c]).astype(np.float32)
+
+
+def appnp_propagate_ref(indptr, indices, data, h, alpha: float, steps: int) -> jax.Array:
+    """Z after ``steps`` steps of Z ← (1 − α)·A·Z + α·H from Z = H, in
+    float32 with plain ``jax.numpy``: each product a ``segment_sum`` of
+    the entries' scaled rows of Z."""
+    n = len(indptr) - 1
+    rows = jnp.asarray(np.repeat(np.arange(n), np.diff(indptr)))
+    cols = jnp.asarray(indices)
+    vals = jnp.asarray(data, jnp.float32)[:, None]
+    h = jnp.asarray(h, jnp.float32)
+    z = h
+    with jax.default_matmul_precision("highest"):
+        for _ in range(steps):
+            az = jax.ops.segment_sum(vals * z[cols], rows, num_segments=n)
+            z = (1.0 - alpha) * az + alpha * h
+    return z

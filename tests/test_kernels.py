@@ -258,16 +258,21 @@ CSR_REF = np.asarray(spmm_csr_ref(CSR))
 LONGEST = int(np.argmax(np.diff(CSR.indptr)))
 
 
+# (start, stop, window, tile, block) of CSR windows, by id
+CSR_WINDOW_CASES = {
+    "start": (0, 64, 64, 64, 16),                     # window at the start
+    "middle": (96, 160, 64, 64, 16),                  # middle, half empty
+    "end": (240, 256, 64, 64, 16),                    # slid back at the end
+    "long-row": (LONGEST, LONGEST + 8, 32, 32, 8),    # a row longer than a tile
+    "empty": (128, 192, 64, 64, 16),                  # only empty rows
+    "ragged": (30, 80, 50, 24, 16),                   # W not a multiple of tile or block
+    "whole": (0, 256, 256, 4096, 128),                # one tile over all
+}
+
+
 class TestSpmmCsr:
-    @pytest.mark.parametrize("start,stop,window,tile,block", [
-        (0, 64, 64, 64, 16),                          # window at the start
-        (96, 160, 64, 64, 16),                        # middle, half empty
-        (240, 256, 64, 64, 16),                       # slid back at the end
-        (LONGEST, LONGEST + 8, 32, 32, 8),            # a row longer than a tile
-        (128, 192, 64, 64, 16),                       # only empty rows
-        (30, 80, 50, 24, 16),                         # W not a multiple of tile or block
-        (0, 256, 256, 4096, 128),                     # one tile over all
-    ], ids=["start", "middle", "end", "long-row", "empty", "ragged", "whole"])
+    @pytest.mark.parametrize("start,stop,window,tile,block", list(CSR_WINDOW_CASES.values()),
+                             ids=list(CSR_WINDOW_CASES))
     def test_window_matches_reference(self, start, stop, window, tile, block):
         assert np.diff(CSR.indptr).max() > 32
         lo = csr_window_start(start, stop, CSR.rows, window)

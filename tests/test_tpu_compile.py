@@ -21,7 +21,9 @@ from jax.sharding import SingleDeviceSharding
 from repro.configs.paper_eneac import HotspotConfig, SpmmConfig
 from repro.kernels.hotspot.hotspot import hotspot_hp_step_pallas, hotspot_hpc_pallas
 from repro.kernels.hotspot.ops import hotspot_hpc_window
-from repro.kernels.spmm.csr import spmm_csr_window
+from repro.kernels.spmm.csr import (appnp_csr_window, put_operand_rows, spmm_csr_window,
+                                    write_window_rows)
+from repro.kernels.spmm.ops import PUT_BLOCK
 from repro.kernels.spmm.ref import COL_BLOCK, ROW_BLOCK
 from repro.kernels.spmm.spmm import spmm_block_ell_window
 
@@ -117,3 +119,26 @@ def test_spmm_csr_window_compiles_at_graph500_scale_20(one_chip):
     assert mem.output_size_in_bytes == 16384 * 128 * 4
     assert mem.temp_size_in_bytes < 64 * 1024 * 1024 < nnz * 4
     assert compiled.as_text().count(" while(") == 1
+
+
+def test_appnp_step_compiles_at_graph500_scale_20(one_chip):
+    # the cell appnp-graph500-s20.hybrid: Â has 2**20 rows and about 32.45M
+    # entries (self-loops included); Z, H and the next operand padded to
+    # 128 lanes; windows of 16384 rows.  The step's scratch stays below any
+    # operand, and each write into the donated next operand aliases it:
+    # nothing of the 537 MB operand is copied.
+    rows, nnz = 1 << 20, 32_450_000
+    z = _sds(one_chip, (rows, 128))
+    i32 = _sds(one_chip, (), jnp.int32)
+    step = appnp_csr_window.lower(
+        _sds(one_chip, (rows + 1,), jnp.int32), _sds(one_chip, (nnz,), jnp.int32),
+        _sds(one_chip, (nnz,)), z, z, i32, window=16384, alpha=0.1).compile()
+    mem = step.memory_analysis()
+    assert mem.output_size_in_bytes == 16384 * 128 * 4
+    assert mem.temp_size_in_bytes < 64 * 1024 * 1024 < nnz * 4
+    assert step.as_text().count(" while(") == 1
+    for write, slab in [(write_window_rows, 16384), (put_operand_rows, PUT_BLOCK)]:
+        compiled = write.lower(z, _sds(one_chip, (slab, 128)), i32, i32, i32).compile()
+        mem = compiled.memory_analysis()
+        assert mem.alias_size_in_bytes == rows * 128 * 4
+        assert mem.temp_size_in_bytes < 2 * slab * 128 * 4
